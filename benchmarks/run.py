@@ -27,6 +27,7 @@ from benchmarks import (bench_batch_size, bench_client_scaling,
                         bench_quorum_kernel, bench_server_scaling,
                         bench_shard_scaling, bench_weights,
                         bench_workloads)
+from repro import compile_cache
 
 SUITES = [
     ("engine", bench_engine),
@@ -62,6 +63,7 @@ def main() -> int:
                          "repro.obs); suites that do not take a trace "
                          "parameter ignore it")
     args = ap.parse_args()
+    compile_cache.enable()
 
     all_lines = []
     t00 = time.time()

@@ -40,7 +40,7 @@ class ObjectWeightTable:
                  decay: float = 0.85):
         self.n = n
         # numpy twin of the jax weight kernel: the simulator path must not
-        # execute jax (forked parallel-shard workers — see weights.py)
+        # execute jax (worker and replica processes — see weights.py)
         self.base = W.geometric_weights_np(n, r)           # descending by rank
         self.half_sum = float(self.base.sum()) / 2.0
         self.decay = decay

@@ -51,9 +51,9 @@ def geometric_weights_np(n: int, r: float,
                          dtype=np.float32) -> np.ndarray:
     """Pure-numpy twin of :func:`geometric_weights` for the event-driven
     simulator's replica constructors: the discrete-event path must stay
-    free of jax *execution* so the parallel sharded runner can fork
-    worker processes without inheriting XLA runtime state (jax documents
-    fork as unsupported once a backend client exists)."""
+    free of jax *execution*, because its worker and served-replica
+    processes must never start a jax backend (an accelerator belongs to
+    the one process that launched them)."""
     if n < 1:
         raise ValueError(f"need at least one replica, got n={n}")
     if not (R_MIN <= r <= R_MAX):

@@ -1,6 +1,7 @@
-"""Jit'd public wrappers: pick the Pallas kernel on TPU, the jnp reference
-elsewhere (this container is CPU: kernels run under interpret=True in the
-test-suite; models call the ref path via cfg.use_pallas == False)."""
+"""Jit'd public wrappers: pick the compiled Pallas kernel on TPU, the jnp
+reference elsewhere. ``force_pallas`` runs the kernel off the TPU, in
+interpret mode unless told otherwise (how the CPU test suite checks it);
+models call the ref path via cfg.use_pallas == False."""
 
 from __future__ import annotations
 

@@ -46,8 +46,8 @@ observable within one engine.
 When to prefer the serial engine
 --------------------------------
 ``workers=1`` remains the right choice for G=1 (nothing to parallelize),
-for tiny runs (fork + per-window IPC overhead dominates), and for
-heavily cross-group workloads, where boundary traffic makes windows
+for tiny runs (worker start-up + per-window IPC overhead dominates), and
+for heavily cross-group workloads, where boundary traffic makes windows
 chatty while each engine has little private work per window.
 """
 
@@ -55,8 +55,8 @@ from __future__ import annotations
 
 import gc
 import multiprocessing as mp
+import sys
 import time
-import warnings
 from typing import Dict, List
 
 from repro.core.simulator import EventEngine
@@ -185,6 +185,21 @@ def _worker_main(conn, cfg: ShardedRunConfig, group_ids: List[int]) -> None:
 # Orchestrator side
 # ---------------------------------------------------------------------------
 
+def _worker_context():
+    """Never fork the calling process: it may hold a jax backend (the
+    chip belongs to one process, and jax does not survive fork). Workers
+    fork from a fresh server process that never starts a backend. Each
+    worker re-imports the caller's main module, so the server preloads
+    it by name along with this module: workers start with imports done."""
+    preload = [__name__]
+    main_spec = getattr(sys.modules["__main__"], "__spec__", None)
+    if main_spec is not None:
+        preload.append(main_spec.name)
+    ctx = mp.get_context("forkserver")
+    ctx.set_forkserver_preload(preload)
+    return ctx
+
+
 def _recv(conn):
     status, payload = conn.recv()
     if status != "ok":
@@ -205,8 +220,7 @@ def run_sharded_parallel(cfg: ShardedRunConfig,
     def engine_of(node_id: int) -> int:
         return node_id // npg if node_id < G * npg else home[node_id]
 
-    ctx = mp.get_context("fork" if "fork" in mp.get_all_start_methods()
-                         else "spawn")
+    ctx = _worker_context()
     conns, procs = [], []
     assign = [[g for g in range(G) if g % W == w] for w in range(W)]
     worker_of = {g: w for w in range(W) for g in assign[w]}
@@ -215,15 +229,7 @@ def run_sharded_parallel(cfg: ShardedRunConfig,
             parent, child = ctx.Pipe()
             p = ctx.Process(target=_worker_main,
                             args=(child, cfg, assign[w]), daemon=True)
-            with warnings.catch_warnings():
-                # jax warns at os.fork() whenever it has been imported in
-                # this process. Workers never execute jax: the simulator
-                # path uses the numpy weight twin (see core/weights.py),
-                # so the inherited XLA state is never touched.
-                warnings.filterwarnings(
-                    "ignore", message=r".*os\.fork\(\).*",
-                    category=RuntimeWarning)
-                p.start()
+            p.start()
             child.close()
             conns.append(parent)
             procs.append(p)

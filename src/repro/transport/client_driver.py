@@ -37,7 +37,7 @@ from repro.core.rsm import history_from_ops
 from repro.core.simulator import Client, Workload
 from repro.transport.codec import decode_body
 from repro.transport.net import NetContext, PeerChannel
-from repro.transport.node_runner import read_addr
+from repro.transport.node_runner import jax_backend_started, read_addr
 
 
 class NetClient(Client):
@@ -125,7 +125,8 @@ async def drive(args) -> int:
     stats = {"client": gid, "done": done,
              "completed_ops": client.completed_ops,
              "committed_in_history": len(hist),
-             "channels": [c.stats() for c in channels]}
+             "channels": [c.stats() for c in channels],
+             "jax_backend": jax_backend_started()}
     (run_dir / f"client-{gid}.stats.json").write_text(json.dumps(stats))
     return 0 if done else 3
 

@@ -32,11 +32,22 @@ import asyncio
 import json
 import os
 import signal
+import sys
 from pathlib import Path
 
 from repro.scenario.registry import protocol_class
 from repro.transport.codec import decode_body, decode_hello, read_frame
 from repro.transport.net import NetContext, PeerChannel
+
+
+def jax_backend_started() -> bool:
+    """Whether this process has started a jax backend. Replica and client
+    processes must not: an accelerator belongs to one process, and that
+    is the one that launched them."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized()
 
 
 def port_file(run_dir: Path, node_id: int) -> Path:
@@ -154,6 +165,7 @@ def _dump(ctx: NetContext, replica, channels, run_dir: Path,
         "recovering": replica.recovering,
         "isolated": replica._isolated,
         "channels": [c.stats() for c in channels],
+        "jax_backend": jax_backend_started(),
     }
     tmp = run_dir / f".node-{node_id}.stats.json.tmp"
     tmp.write_text(json.dumps(stats, indent=1))

@@ -11,12 +11,6 @@ import textwrap
 
 import pytest
 
-try:
-    from jax.sharding import AxisType  # noqa: F401
-except ImportError:
-    pytest.skip("installed jax lacks jax.sharding.AxisType (needed by "
-                "repro.launch.mesh)", allow_module_level=True)
-
 
 def _run(code: str) -> dict:
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,

@@ -3,21 +3,10 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.launch.hlo_analysis import analyze_hlo
 from repro.launch.roofline import Roofline
-
-
-# Capability gate on the jax *version*, not on the analyzer's own answer
-# (that would silently skip on analyzer regressions): releases predating
-# jax.sharding.AxisType lower scans into an HLO text dialect whose flop
-# accounting this analyzer does not target.
-if not hasattr(jax.sharding, "AxisType"):
-    pytest.skip("installed jax predates the HLO scan dialect this "
-                "analyzer targets (no jax.sharding.AxisType)",
-                allow_module_level=True)
 
 
 @given(L=st.integers(2, 12), B=st.sampled_from([8, 32]),

@@ -41,6 +41,25 @@ def test_quorum_commit_matches_ref(data):
                                rtol=1e-4)
 
 
+def test_quorum_commit_ties_follow_replica_order():
+    """Votes arriving at the same time count in replica order, as a
+    stable sort orders them: the quorum size follows from that order."""
+    arrivals = np.array([[1.0, 1.0, 1.0, 2.0, np.inf],
+                         [2.0, 1.0, 1.0, 1.0, 1.0],
+                         [3.0, 3.0, 3.0, 3.0, 3.0]], np.float32)
+    weights = np.array([[1.0, 1.0, 5.0, 1.0, 1.0],
+                        [9.0, 1.0, 1.0, 1.0, 1.0],
+                        [1.0, 1.0, 1.0, 1.0, 1.0]], np.float32)
+    ct, qs, cm, ws = quorum_commit_pallas(jnp.asarray(arrivals),
+                                          jnp.asarray(weights),
+                                          interpret=True)
+    rct, rqs, rcm, rws = ref.quorum_commit_ref(arrivals, weights)
+    np.testing.assert_array_equal(np.asarray(cm), [True, True, True])
+    np.testing.assert_array_equal(np.asarray(qs), [3, 5, 3])
+    for got, want in ((cm, rcm), (ct, rct), (qs, rqs), (ws, rws)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_quorum_commit_geometric_weights_top2():
     from repro.core import weights as W
     w = np.tile(np.asarray(W.geometric_weights(7, 1.9)), (4, 1))

@@ -85,6 +85,28 @@ def test_workers_exceeding_groups_degenerate():
     assert _metrics(serial.result) == _metrics(parallel.result)
 
 
+def test_workers_never_fork_a_process_holding_jax(monkeypatch):
+    """The caller may hold the chip: a jax backend does not survive
+    fork, and a forked child would inherit the device client. Workers
+    must start without forking the calling process."""
+    import os
+
+    import jax
+    jax.devices()                     # the caller holds a backend
+
+    def refuse_fork():
+        raise AssertionError("parallel runner forked its caller")
+
+    monkeypatch.setattr(os, "fork", refuse_fork)
+    cfg = dict(n_groups=2, n_replicas_per_group=3, total_ops=600,
+               batch_size=10, locality="mixed", seed=5)
+    parallel = run_sharded(ShardedRunConfig(**cfg, workers=2))
+    assert parallel.result.workers == 2
+    monkeypatch.undo()
+    serial = run_sharded(ShardedRunConfig(**cfg, workers=1))
+    assert _metrics(serial.result) == _metrics(parallel.result)
+
+
 def test_workers_auto_and_g1_fall_back_to_serial():
     """G=1 has nothing to parallelize: any workers value runs the serial
     engine (artifacts keep live sim/replica state)."""

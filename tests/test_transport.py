@@ -131,6 +131,11 @@ def test_served_cluster_history_linearizable_and_bounded():
                             if k.startswith("ops_committed_total"))
     assert committed_by_path == cfg.total_ops
 
+    # no replica or client process starts a jax backend: the chip
+    # belongs to the process that launched them
+    assert len(r.client_stats) == cfg.n_clients
+    assert not any(s["jax_backend"] for s in r.node_stats + r.client_stats)
+
     # soak bounds: queues respect their cap and drain at shutdown,
     # nothing reconnected on a healthy cluster, read-result capture
     # stays under its FIFO cap, and every replica applied every op
