@@ -42,6 +42,24 @@ Reads served locally under a lease (path ``"local"``) get their own
 breakdown bucket — they never run a quorum round, so their latency is
 ingress plus coordinator service.
 
+Served runs also record a ``vote`` span on each responder (its handling
+of a proposal, ``repro.transport.net``). Every counted vote — an accept
+that reached the coordinator at or before its round's decision, one the
+quorum waited on — is joined by ``(path, proposer, round id, responder)``
+to its proposal and its accept, and split into three legs summed in
+``CriticalPathReport.votes``:
+
+  ``out``      proposal -> responder handler start (the sender's queue,
+               the socket and the responder's loop),
+  ``service``  responder handler start -> end (the accept is posted in
+               it),
+  ``back``     handler end -> the coordinator handles the accept.
+
+For the decisive vote of a round the three legs add up to its
+propose -> decision time. A leg below -50 µs can only be a clock fault,
+and is counted. Simulator traces carry no ``vote`` span: their ``votes``
+stays empty.
+
 Path mix (``fast_frac``) is computed from the *always-recorded* commit
 stamp events, so it equals ``collect_metrics``/``assemble_result`` path
 fractions exactly even when per-op span sampling is enabled — the obs
@@ -53,6 +71,8 @@ from __future__ import annotations
 import bisect
 import dataclasses
 from typing import Dict, List, Optional, Tuple
+
+CLOCK_FAULT_S = -50e-6       # a vote leg below this is a clock fault
 
 _COMPONENTS = ("ingress_s", "coord_s", "queue_s", "quorum_link_s",
                "straggler_s", "dep_stall_s", "lease_s", "reassign_s",
@@ -96,6 +116,27 @@ class PathBreakdown:
 
 
 @dataclasses.dataclass
+class VoteLegs:
+    """Counted votes of served runs, split into legs (module docstring)."""
+    count: int = 0
+    out_s: float = 0.0
+    service_s: float = 0.0
+    back_s: float = 0.0
+    clock_faults: int = 0               # legs below CLOCK_FAULT_S
+
+    def add(self, out: float, service: float, back: float) -> None:
+        self.count += 1
+        self.out_s += out
+        self.service_s += service
+        self.back_s += back
+        self.clock_faults += sum(1 for leg in (out, service, back)
+                                 if leg < CLOCK_FAULT_S)
+
+    def to_dict(self) -> dict:
+        return dataclasses.asdict(self)
+
+
+@dataclasses.dataclass
 class CriticalPathReport:
     committed: int
     fast_committed: int
@@ -109,6 +150,7 @@ class CriticalPathReport:
     # closed each quorum — the node everyone was waiting for
     straggler_by_node: Dict[int, float]
     analyzed: int                       # ops with a complete span
+    votes: VoteLegs = dataclasses.field(default_factory=VoteLegs)
 
     def top_straggler(self) -> Optional[int]:
         """The node charged the most quorum-straggler time."""
@@ -130,6 +172,7 @@ class CriticalPathReport:
             "local": self.local.to_dict(),
             "straggler_by_node": {str(k): v for k, v in
                                   sorted(self.straggler_by_node.items())},
+            "votes": self.votes.to_dict(),
         }
 
 
@@ -156,6 +199,11 @@ def analyze_events(events: List[tuple],
     lease_wait_t: Dict[Tuple[int, int], float] = {}    # (node, op) -> t
     coding_wait_t: Dict[Tuple[int, int], float] = {}   # (node, op) -> t
     installs: List[float] = []                         # weight-view installs
+    proposer: Dict[Tuple[str, int], int] = {}          # round -> node
+    # (path, proposer, round, responder) -> (handler start, handler end)
+    votes: Dict[Tuple[str, int, int, int], Tuple[float, float]] = {}
+    # round -> (propose t, latest decision of its analyzed ops)
+    rounds: Dict[Tuple[str, int], Tuple[float, float]] = {}
 
     for e in events:
         t, kind, node = e[0], e[1], e[2]
@@ -166,6 +214,7 @@ def analyze_events(events: List[tuple],
         elif kind == "fast_propose":
             fb_of_op.setdefault(e[4], e[3])
             fb_propose.setdefault(e[3], t)
+            proposer.setdefault(("f", e[3]), node)
         elif kind == "fast_accept":
             accepts.setdefault(("f", e[3]), []).append((t, e[4]))
         elif kind == "fast_commit":
@@ -175,6 +224,7 @@ def analyze_events(events: List[tuple],
         elif kind == "slow_propose":
             inst_of_op.setdefault(e[4], e[3])
             inst_propose.setdefault(e[3], t)
+            proposer.setdefault(("s", e[3]), node)
         elif kind == "slow_accept":
             accepts.setdefault(("s", e[3]), []).append((t, e[4]))
         elif kind == "slow_commit":
@@ -187,6 +237,8 @@ def analyze_events(events: List[tuple],
             coding_wait_t.setdefault((node, e[3]), t)
         elif kind == "weight_install":
             installs.append(t)
+        elif kind == "vote":
+            votes.setdefault((e[3], e[5], e[4], node), (t, e[6]))
     installs.sort()
 
     fast_bd, slow_bd, local_bd = (PathBreakdown(), PathBreakdown(),
@@ -220,6 +272,7 @@ def analyze_events(events: List[tuple],
             arr = [a for a in accepts.get(("f", fb), ())
                    if a[0] <= decide_t]
             parts, decisive = _quorum_parts(propose_t, decide_t, arr)
+            _note_round(rounds, ("f", fb), propose_t, decide_t)
             stall = stall_t.get((commit_node, op_id))
             if cw_t is not None:
                 # shard-durability pause: the weighted-reconstructable
@@ -261,6 +314,7 @@ def analyze_events(events: List[tuple],
             arr = [a for a in accepts.get(("s", inst), ())
                    if a[0] <= decide_t]
             parts, decisive = _quorum_parts(propose_t, decide_t, arr)
+            _note_round(rounds, ("s", inst), propose_t, decide_t)
             if cw_t is not None:
                 end = (wait_t if wait_t is not None and wait_t >= cw_t
                        else commit_t)
@@ -299,13 +353,32 @@ def analyze_events(events: List[tuple],
                 straggler_by_node[src] = \
                     straggler_by_node.get(src, 0.0) + amount
 
+    legs = VoteLegs()
+    if votes:
+        for (tag, rid), (propose_t, decide_t) in sorted(rounds.items()):
+            path = "fast" if tag == "f" else "slow"
+            src_node = proposer.get((tag, rid))
+            for accept_t, src in accepts.get((tag, rid), ()):
+                v = votes.get((path, src_node, rid, src))
+                if v is not None and accept_t <= decide_t:
+                    legs.add(v[0] - propose_t, v[1] - v[0], accept_t - v[1])
+
     committed = n_fast + n_slow + n_local
     return CriticalPathReport(
         committed=committed, fast_committed=n_fast, slow_committed=n_slow,
         local_committed=n_local,
         fast_frac=n_fast / committed if committed else 0.0,
         fast=fast_bd, slow=slow_bd, local=local_bd,
-        straggler_by_node=straggler_by_node, analyzed=analyzed)
+        straggler_by_node=straggler_by_node, analyzed=analyzed, votes=legs)
+
+
+def _note_round(rounds: dict, key: Tuple[str, int], propose_t: float,
+                decide_t: float) -> None:
+    """Record a round an analyzed op waited on; a round whose ops were
+    decided at different accepts keeps its latest decision."""
+    seen = rounds.get(key)
+    if seen is None or decide_t > seen[1]:
+        rounds[key] = (propose_t, decide_t)
 
 
 def _install_in(installs: List[float], lo: float, hi: float) -> bool:

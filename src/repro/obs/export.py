@@ -44,6 +44,7 @@ ARG_NAMES: Dict[str, Sequence[str]] = {
     "slow_propose": ("inst", "op_id"),
     "slow_accept": ("inst", "src", "psum"),
     "slow_commit": ("inst", "op_id"),
+    "vote":        ("path", "round", "proposer", "t_post"),
     "epx_reply":   ("batch", "phase", "src"),
     "commit":      ("op_id", "path"),
     "dep_stall":   ("op_id", "obj", "n_deps"),

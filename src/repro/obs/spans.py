@@ -85,6 +85,7 @@ _NODE_ARG_IDX = {
     "ema": 0,            # peer
     "slow_forward": 1,   # leader
     "weight_suspect": 1,  # leader (report target)
+    "vote": 2,           # proposer
 }
 
 # event kinds carrying a comma-joined replica-id list at this arg index

@@ -33,7 +33,22 @@ capped, and the ``read_results`` / ``commit_log`` reply-enrichment
 tables prune FIFO above a fixed cap (a retried op older than 64k
 credits would lose its path stamp in the reply — it keeps its ack).
 Nothing here grows with the op count of the run except the tracer,
-which is explicitly sampled.
+which is explicitly sampled (the ``vote`` spans below are tracer
+events); a timed channel's queue stamps ride its bounded queue.
+
+Served-path instrumentation, on only in a traced replica (off, each
+costs one ``None`` or flag test per frame):
+
+  * ``vote`` — ``(t_recv, "vote", responder, path, id, proposer,
+    t_post)`` for every ``fast_propose`` / ``slow_propose`` frame that
+    carries a sampled op: the responder's handler start and end (the
+    accept is posted inside it). Joined with the coordinator's proposal
+    and accept events, it splits each vote into outbound leg, responder
+    service and return leg (``repro.obs.critical_path``). It is a span
+    of the tracer and shares its sampling.
+  * ``PeerChannel(timed=True)`` stamps each frame as it is queued and
+    sums the time until ``writer.write`` into ``wait_s``: one float
+    per channel, whatever the op count.
 """
 
 from __future__ import annotations
@@ -48,6 +63,9 @@ from repro.transport.codec import encode_hello, encode_msg
 
 READ_RESULTS_CAP = 65536      # reply-enrichment table bound (FIFO prune)
 WRITE_BUF_LIMIT = 8 * 1024 * 1024   # per-client-socket backpressure bound
+# proposal kinds a responder votes on: kind -> (path, payload key of the
+# round's id)
+VOTE_KINDS = {"fast_propose": ("fast", "fb"), "slow_propose": ("slow", "inst")}
 
 
 class TransportTimer:
@@ -154,8 +172,18 @@ class NetContext:
 
     def deliver(self, msg: Msg) -> None:
         """Inbound frame -> protocol handler (called by the node
-        runner's connection reader)."""
-        self._node.on_message(msg, self.now)
+        runner's connection reader). A traced replica records a ``vote``
+        span around its handling of a proposal (module docstring)."""
+        t_recv = self.now
+        self._node.on_message(msg, t_recv)
+        tr = self.tracer
+        if tr is not None:
+            vote = VOTE_KINDS.get(msg.kind)
+            if vote is not None and any(tr.sampled(op.op_id)
+                                        for op in msg.payload["ops"]):
+                path, key = vote
+                tr.ev("vote", t_recv, self.local_id, path,
+                      msg.payload[key], msg.src, self.now)
 
     def _enrich_reply(self, payload: dict) -> None:
         """Attach read results + commit paths to an outgoing credit
@@ -242,6 +270,10 @@ class PeerChannel:
     A transport with this bug must fail the linearizability checker —
     that is what makes the checker-on-real-histories pipeline
     trustworthy.
+
+    ``timed=True`` (a traced replica) queues each frame with the
+    ``perf_counter`` time it was queued and adds its wait until
+    ``writer.write`` to ``wait_s``; otherwise the queue holds bare frames.
     """
 
     REORDER_EVERY = 4     # hold every 4th frame ...
@@ -250,12 +282,14 @@ class PeerChannel:
     def __init__(self, src: int, dst: int,
                  addr_fn: Callable[[], Optional[tuple]], *,
                  max_queue: int = 512, reorder: bool = False,
+                 timed: bool = False,
                  on_frame: Optional[Callable[[bytes], None]] = None):
         self.src = src
         self.dst = dst
         self.addr_fn = addr_fn
         self.max_queue = max_queue
         self.reorder = reorder
+        self.timed = timed
         self.on_frame = on_frame       # clients: replies ride this socket
         self._q: deque = deque()
         self._held: Optional[bytes] = None     # reorder twin: displaced frame
@@ -266,6 +300,7 @@ class PeerChannel:
         # soak-visible stats: every one of these is bounded per the
         # module contract; queue_hwm <= max_queue is asserted in tests
         self.sent = 0
+        self.wait_s = 0.0                      # timed: queued -> written
         self.dropped = 0
         self.reconnects = 0
         self.queue_hwm = 0
@@ -295,7 +330,9 @@ class PeerChannel:
         if len(self._q) >= self.max_queue:
             self._q.popleft()              # drop-oldest: retransmit
             self.dropped += 1              # timers / client retries
-        self._q.append(data)               # re-drive consensus traffic
+        if self.timed:                     # re-drive consensus traffic
+            data = (time.perf_counter(), data)
+        self._q.append(data)
         if len(self._q) > self.queue_hwm:
             self.queue_hwm = len(self._q)
         self._wake.set()
@@ -348,7 +385,11 @@ class PeerChannel:
                                 self._push(self._held)
                                 self._held = None
                         continue
-                    writer.write(self._q.popleft())
+                    frame = self._q.popleft()
+                    if self.timed:
+                        queued, frame = frame
+                        self.wait_s += time.perf_counter() - queued
+                    writer.write(frame)
                     self.sent += 1
                     if not self._q:
                         await writer.drain()
@@ -381,6 +422,7 @@ class PeerChannel:
             pass
 
     def stats(self) -> dict:
-        return {"dst": self.dst, "sent": self.sent, "dropped": self.dropped,
+        return {"dst": self.dst, "sent": self.sent, "wait_s": self.wait_s,
+                "dropped": self.dropped,
                 "reconnects": self.reconnects, "queue_hwm": self.queue_hwm,
                 "queue_len": len(self._q), "max_queue": self.max_queue}

@@ -19,6 +19,23 @@ On SIGTERM/SIGINT the runner dumps its raw tracer events to
 ``node-<id>.stats.json`` before exiting; the launcher merges the per-node
 traces through the same ``canonical_events`` path simulator runs use.
 
+``stats.json`` holds the engine's counters (``messages``, ``applied``,
+``recovering``, ``isolated``, ...) and each outbound channel's
+(``sent``, ``wait_s``, ``dropped``, ``reconnects``, ``queue_hwm``, ...).
+With ``--trace`` the replica's host is sampled too, every row stamped on
+the cluster clock (``NetContext.now``) so a reader can cut any window
+out of it:
+
+  * ``loop_lag``: ``[t, lag_s]`` each time a 10 ms sleep wakes, ``lag_s``
+    being how much later than asked it woke;
+  * ``host``: ``[t, cpu_s, frames_sent, chan_wait_s]`` every 0.25 s —
+    the process's CPU time (``time.process_time``, all threads), and the
+    frames written and their summed queue wait over every outbound
+    channel (``PeerChannel(timed=True)``).
+
+These rows grow with the run's length (about 100 a second), not with
+its op count; an untraced replica records none.
+
 ``--recover`` marks a restarted process: after boot it enters the
 protocol's crash-recovery flow (state transfer from a live peer) instead
 of claiming fresh state — the same ``on_recover`` hook the simulator's
@@ -33,11 +50,15 @@ import json
 import os
 import signal
 import sys
+import time
 from pathlib import Path
 
 from repro.scenario.registry import protocol_class
 from repro.transport.codec import decode_body, decode_hello, read_frame
 from repro.transport.net import NetContext, PeerChannel
+
+LAG_PERIOD_S = 0.01         # the loop-lag probe's sleep
+HOST_PERIOD_S = 0.25        # between two ``host`` rows
 
 
 def jax_backend_started() -> bool:
@@ -119,7 +140,8 @@ async def serve(args) -> None:
             continue
         chan = PeerChannel(args.node_id, j,
                            lambda j=j: read_addr(run_dir, j),
-                           max_queue=args.max_queue, reorder=args.reorder)
+                           max_queue=args.max_queue, reorder=args.reorder,
+                           timed=args.trace)
         ctx.register_peer(j, chan.send)
         channels.append(chan)
 
@@ -135,20 +157,43 @@ async def serve(args) -> None:
     if args.recover:
         replica.on_recover(ctx.now)
 
+    host = {"loop_lag": [], "host": []} if args.trace else {}
+    sampler = (asyncio.ensure_future(sample_host(ctx, channels, host))
+               if host else None)
+
     stop = asyncio.Event()
     loop = asyncio.get_running_loop()
     for sig in (signal.SIGTERM, signal.SIGINT):
         loop.add_signal_handler(sig, stop.set)
     await stop.wait()
 
+    if sampler is not None:
+        sampler.cancel()
     server.close()
     for chan in channels:
         await chan.close()
-    _dump(ctx, replica, channels, run_dir, args.node_id)
+    _dump(ctx, replica, channels, run_dir, args.node_id, host)
+
+
+async def sample_host(ctx: NetContext, channels, rows: dict) -> None:
+    """Append ``loop_lag`` and ``host`` rows (module docstring) until
+    cancelled."""
+    next_host = ctx.now
+    while True:
+        asked = time.perf_counter()
+        await asyncio.sleep(LAG_PERIOD_S)
+        lag = time.perf_counter() - asked - LAG_PERIOD_S
+        now = ctx.now
+        rows["loop_lag"].append([now, lag])
+        if now >= next_host:
+            rows["host"].append([now, time.process_time(),
+                                 sum(c.sent for c in channels),
+                                 sum(c.wait_s for c in channels)])
+            next_host = now + HOST_PERIOD_S
 
 
 def _dump(ctx: NetContext, replica, channels, run_dir: Path,
-          node_id: int) -> None:
+          node_id: int, host: dict) -> None:
     if ctx.tracer is not None:
         with open(run_dir / f"node-{node_id}.trace.jsonl", "w") as f:
             for ev in ctx.tracer.events:
@@ -166,6 +211,7 @@ def _dump(ctx: NetContext, replica, channels, run_dir: Path,
         "isolated": replica._isolated,
         "channels": [c.stats() for c in channels],
         "jax_backend": jax_backend_started(),
+        **host,
     }
     tmp = run_dir / f".node-{node_id}.stats.json.tmp"
     tmp.write_text(json.dumps(stats, indent=1))

@@ -163,6 +163,54 @@ def test_analyze_window_partitions_commits():
     assert lo.fast_committed + hi.fast_committed == full.fast_committed
 
 
+# A served trace by hand: a fast round (batch 7, proposer 0) whose votes
+# from replicas 1 and 2 arrive before its decision and whose vote from 3
+# arrives after it, and a slow round (instance 9, leader 1) whose one vote
+# reads its proposal before it was sent (a clock fault).
+_SERVED = [
+    (0.9980, "ingress", 0, 100, 5, 0.9975, 20),
+    (1.0000, "fast_propose", 0, 7, 100),
+    (1.0010, "vote", 1, "fast", 7, 0, 1.0012),
+    (1.0015, "vote", 2, "fast", 7, 0, 1.0016),
+    (1.0020, "fast_accept", 0, 7, 1, 0),
+    (1.0030, "fast_accept", 0, 7, 2, 1),
+    (1.0030, "fast_commit", 0, 7, 100),
+    (1.0030, "commit", 0, 100, "fast"),
+    (1.0040, "vote", 3, "fast", 7, 0, 1.0041),
+    (1.0050, "fast_accept", 0, 7, 3, 0),
+    (1.9900, "ingress", 1, 200, 6, 1.9890, 21),
+    (1.9950, "slow_enqueue", 1, 200),
+    (1.9000, "vote", 2, "slow", 9, 1, 1.9001),
+    (2.0000, "slow_propose", 1, 9, 200),
+    (2.0020, "slow_accept", 1, 9, 2, 0.7),
+    (2.0020, "slow_commit", 1, 9, 200),
+    (2.0020, "commit", 1, 200, "slow"),
+]
+
+
+def test_critical_path_splits_counted_votes_into_legs():
+    events = canonical_events(_SERVED)
+    rep = analyze_events(events)
+    legs = rep.votes
+    # the vote from 3 came after the decision: not counted
+    assert legs.count == 3
+    assert legs.out_s == pytest.approx(0.0010 + 0.0015 - 0.1000)
+    assert legs.service_s == pytest.approx(0.0002 + 0.0001 + 0.0001)
+    assert legs.back_s == pytest.approx(0.0008 + 0.0014 + 0.1019)
+    assert legs.clock_faults == 1
+    assert rep.to_dict()["votes"] == dataclasses.asdict(legs)
+    # the vote spans change no other field of the report
+    bare = analyze_events([e for e in events if e[1] != "vote"])
+    assert bare.votes.count == 0
+    with_votes = rep.to_dict()
+    with_votes.pop("votes")
+    without = bare.to_dict()
+    without.pop("votes")
+    assert with_votes == without
+    # a window that leaves the fast commit out leaves its votes out too
+    assert analyze_events(events, window=(1.5, 3.0)).votes.count == 1
+
+
 # ---------------------------------------------------------------------------
 # commit_log release (satellite: unbounded growth fix)
 # ---------------------------------------------------------------------------
@@ -224,8 +272,10 @@ def test_mapped_tracer_translates_node_and_replica_args():
     mt = MappedTracer(root, lambda n: n + 10 if n < 3 else n)
     mt.ev("fast_accept", 1.0, 1, 7, 2, 1)     # src arg (idx 1) is local
     mt.ev("ingress", 2.0, 0, 42, 9, 1.5, 100)  # client id untouched
+    mt.ev("vote", 3.0, 1, "fast", 7, 2, 3.5)   # proposer arg (idx 2)
     assert root.events[0] == (1.0, "fast_accept", 11, 7, 12, 1)
     assert root.events[1] == (2.0, "ingress", 10, 42, 9, 1.5, 100)
+    assert root.events[2] == (3.0, "vote", 11, "fast", 7, 12, 3.5)
 
 
 def test_canonical_events_dedupes_commits_keeping_earliest():

@@ -14,6 +14,7 @@ import time
 import pytest
 
 from repro.core.simulator import Msg, Op
+from repro.obs.critical_path import analyze_events
 from repro.transport import ClusterConfig, ClusterLauncher, run_served
 from repro.transport.codec import (decode_body, decode_hello, encode_hello,
                                    encode_msg, split_frames)
@@ -153,6 +154,27 @@ def test_served_cluster_history_linearizable_and_bounded():
             assert ch["queue_len"] <= ch["max_queue"]
             assert ch["reconnects"] == 0
 
+    # served-path tracing: responders record a vote span per sampled
+    # proposal, which joins to the coordinator's rounds, and every
+    # replica samples its host on the cluster clock
+    votes = [e for e in r.trace if e[1] == "vote"]
+    assert votes
+    for t_recv, _, responder, path, _, proposer, t_post in votes:
+        assert path in ("fast", "slow")
+        assert responder != proposer and t_post >= t_recv
+    legs = analyze_events(r.trace).votes
+    assert 0 < legs.count <= len(votes)
+    assert legs.clock_faults == 0
+    for ns in r.node_stats:
+        lag, host = ns["loop_lag"], ns["host"]
+        assert lag and all(len(row) == 2 for row in lag)
+        assert len(host) >= 2 and all(len(row) == 4 for row in host)
+        for rows in (lag, host):
+            assert [row[0] for row in rows] == sorted(row[0] for row in rows)
+        assert host[-1][1] >= host[0][1] and host[-1][2] >= host[0][2]
+        assert host[-1][2] <= sum(ch["sent"] for ch in ns["channels"])
+        assert sum(ch["wait_s"] for ch in ns["channels"]) > 0
+
 
 # ---------------------------------------------------------------------------
 # crash + recovery over sockets
@@ -188,6 +210,11 @@ def test_served_crash_restart_recovers_over_sockets():
 
     stats = {ns["node"]: ns for ns in r.node_stats}
     assert set(stats) == set(range(cfg.n_replicas))
+    # untraced: no spans, no host rows, no queue stamps
+    assert r.trace == []
+    for ns in stats.values():
+        assert "loop_lag" not in ns and "host" not in ns
+        assert all(ch["wait_s"] == 0.0 for ch in ns["channels"])
     # the restarted replica finished recovery and holds real state
     assert not stats[0]["recovering"]
     assert stats[0]["applied"] > 0
