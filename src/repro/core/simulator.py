@@ -270,6 +270,13 @@ class Node:
 
     def broadcast(self, dsts: Sequence[int], kind: str, payload: dict,
                   size_ops: int = 0, size_bytes: int = 0):
+        # the served engine encodes a broadcast's payload once for all
+        # destinations; a simulator engine takes one post per destination
+        post_many = getattr(self.sim, "post_many", None)
+        if post_many is not None:
+            post_many(Msg(kind, self.node_id, -1, payload, size_ops,
+                          size_bytes), dsts)
+            return
         for d in dsts:
             self.send(d, kind, payload, size_ops, size_bytes)
 

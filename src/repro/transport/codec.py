@@ -19,6 +19,10 @@ uses keys that collide with the tag space (asserted on encode). numpy
 scalars are converted to native ints/floats on the way out so the codec
 stays dependency-free on the receive side.
 
+A broadcast's frames differ only in the destination id, so
+:func:`encode_fanout` packs the payload once and splices each id into
+copies of the envelope; its frames are byte for byte ``encode_msg``'s.
+
 The framing and the codec are deliberately independent of asyncio: the
 unit tests round-trip encoded messages without opening a socket.
 """
@@ -27,7 +31,7 @@ from __future__ import annotations
 
 import json
 import struct
-from typing import Tuple
+from typing import List, Sequence, Tuple
 
 import numpy as np
 
@@ -91,6 +95,14 @@ def _dec(x):
     return x
 
 
+def _check_body(n: int, msg: Msg) -> None:
+    if n > MAX_FRAME:
+        raise ValueError(
+            f"encoded frame body is {n} bytes, exceeds MAX_FRAME "
+            f"({MAX_FRAME}): refusing to emit an undecodable frame "
+            f"(kind={msg.kind!r}, size_ops={msg.size_ops})")
+
+
 def encode_msg(msg: Msg) -> bytes:
     """One framed message: header + tagged body. Raises ``ValueError``
     if the encoded body exceeds ``MAX_FRAME`` — the sender must refuse
@@ -106,12 +118,43 @@ def encode_msg(msg: Msg) -> bytes:
         body = msgpack.packb(tree, use_bin_type=True)
     else:
         body = json.dumps(tree, separators=(",", ":")).encode()
-    if len(body) > MAX_FRAME:
-        raise ValueError(
-            f"encoded frame body is {len(body)} bytes, exceeds MAX_FRAME "
-            f"({MAX_FRAME}): refusing to emit an undecodable frame "
-            f"(kind={msg.kind!r}, size_ops={msg.size_ops})")
+    _check_body(len(body), msg)
     return HEADER.pack(len(body)) + body
+
+
+def encode_fanout(msg: Msg, dsts: Sequence[int]) -> List[bytes]:
+    """The frames of ``msg`` sent to each id of ``dsts``, in order
+    (``msg.dst`` is not read): each is byte for byte
+    ``encode_msg`` of the message with that destination, but the payload
+    tree is tagged and packed once. A body is its envelope's keys in
+    ``encode_msg``'s order, so every frame is the bytes before the
+    destination id, the id, and the bytes after it."""
+    p = _enc(msg.payload)
+    if msgpack is not None:
+        packer = msgpack.Packer(use_bin_type=True)
+        pack = packer.pack
+        head = packer.pack_map_header(6 if msg.size_bytes else 5) \
+            + pack("k") + pack(msg.kind) + pack("s") + pack(msg.src) \
+            + pack("d")
+        tail = pack("z") + pack(msg.size_ops) + pack("p") + pack(p)
+        if msg.size_bytes:
+            tail += pack("b") + pack(msg.size_bytes)
+    else:
+        def pack(x):
+            return json.dumps(x, separators=(",", ":")).encode()
+        head = b'{"k":' + pack(msg.kind) + b',"s":' + pack(msg.src) \
+            + b',"d":'
+        tail = b',"z":' + pack(msg.size_ops) + b',"p":' + pack(p)
+        if msg.size_bytes:
+            tail += b',"b":' + pack(msg.size_bytes)
+        tail += b"}"
+    frames = []
+    for d in dsts:
+        dst = pack(d)
+        n = len(head) + len(dst) + len(tail)
+        _check_body(n, msg)
+        frames.append(HEADER.pack(n) + head + dst + tail)
+    return frames
 
 
 def decode_body(body: bytes) -> Msg:

@@ -17,6 +17,9 @@ different substrate:
     handler's sends must not recurse into handlers, exactly like the
     simulator's event queue), replicas via their :class:`PeerChannel`,
     clients via the inbound socket they dialed in on;
+  * ``post_many`` is a broadcast: its payload is encoded once and each
+    destination's frame spliced from those bytes; the simulator engines
+    have no bytes to share and do not offer it;
   * ``busy`` is a no-op — real CPU time charges itself.
 
 Clock-domain caveat: ``time.time`` can step (NTP); on a single host the
@@ -56,10 +59,10 @@ from __future__ import annotations
 import asyncio
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Sequence
 
 from repro.core.simulator import CostModel, Msg
-from repro.transport.codec import encode_hello, encode_msg
+from repro.transport.codec import encode_fanout, encode_hello, encode_msg
 
 READ_RESULTS_CAP = 65536      # reply-enrichment table bound (FIFO prune)
 WRITE_BUF_LIMIT = 8 * 1024 * 1024   # per-client-socket backpressure bound
@@ -108,6 +111,10 @@ class NetContext:
         self._node = None
         self._senders: Dict[int, Callable[[bytes], None]] = {}
         self.stats_messages = 0
+        self.encodes = 0               # frame bodies encoded: one per
+                                       # frame ``post`` encodes alone,
+                                       # one per ``post_many``
+        self._frames: Optional[dict] = None    # post_many: dst -> frame
         self.dropped_no_route = 0      # sends with no live route (peer
                                        # down / client gone): the
                                        # transport twin of a cut link
@@ -163,7 +170,30 @@ class NetContext:
         if sender is None:
             self.dropped_no_route += 1
             return
-        sender(encode_msg(msg))
+        frames = self._frames
+        if frames is None:
+            self.encodes += 1
+            sender(encode_msg(msg))
+        else:
+            sender(frames[msg.dst])
+
+    def post_many(self, msg: Msg, dsts: Sequence[int]) -> None:
+        """A broadcast: ``post`` of ``msg`` to each id of ``dsts`` (its
+        own ``dst`` is not read), with the payload encoded once for all
+        of them (``encode_fanout``). Each destination still goes through
+        ``post``, which takes its frame from the broadcast's, so
+        counting, loopback and routing are those of single sends, and a
+        wrapper of ``post`` sees every destination. A ``client_reply``
+        is enriched per destination and so encoded per destination."""
+        if msg.kind != "client_reply" and dsts:
+            self._frames = dict(zip(dsts, encode_fanout(msg, dsts)))
+            self.encodes += 1
+        try:
+            for d in dsts:
+                self.post(Msg(msg.kind, msg.src, d, msg.payload,
+                              msg.size_ops, msg.size_bytes))
+        finally:
+            self._frames = None
 
     # -- transport plumbing --------------------------------------------------
 

@@ -19,8 +19,8 @@ On SIGTERM/SIGINT the runner dumps its raw tracer events to
 ``node-<id>.stats.json`` before exiting; the launcher merges the per-node
 traces through the same ``canonical_events`` path simulator runs use.
 
-``stats.json`` holds the engine's counters (``messages``, ``applied``,
-``recovering``, ``isolated``, ...) and each outbound channel's
+``stats.json`` holds the engine's counters (``messages``, ``encodes``,
+``applied``, ``recovering``, ``isolated``, ...) and each outbound channel's
 (``sent``, ``wait_s``, ``dropped``, ``reconnects``, ``queue_hwm``, ...).
 With ``--trace`` the replica's host is sampled too, every row stamped on
 the cluster clock (``NetContext.now``) so a reader can cut any window
@@ -202,6 +202,7 @@ def _dump(ctx: NetContext, replica, channels, run_dir: Path,
         "node": node_id,
         "now": ctx.now,
         "messages": ctx.stats_messages,
+        "encodes": ctx.encodes,
         "dropped_no_route": ctx.dropped_no_route,
         "applied": replica.rsm.apply_count,
         "store_size": len(replica.rsm.store),

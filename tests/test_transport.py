@@ -105,6 +105,122 @@ def test_codec_oversize_frames_rejected_both_ends():
         encode_msg(Msg("blob", 0, 1, {"v": big}, 1))
 
 
+def _op(i, size=0):
+    return Op(i, 5, 0x2000000000000000 + i, "w", 1234 + i, 0.5, -1.0, "",
+              None, size)
+
+
+FANOUT_SHAPES = {
+    "ops": lambda: Msg("fast_propose", 0, -1,
+                       {"ops": [_op(i) for i in range(10)], "fb": 17}, 10),
+    "dep_map": lambda: Msg("fast_commit", 2, -1,
+                           {"fb": 3, "deps": {7: [3, 4], 9: []},
+                            "ok": True}, 0),
+    "set_tuple": lambda: Msg("slow_commit", 1, -1,
+                             {"applied": {1, 2, 3},
+                              "buf": [(_op(1), None, "slow")],
+                              "store": {9: 42}, "t": 0.25}, 1),
+    "size_bytes": lambda: Msg("fast_propose", 4, -1,
+                              {"ops": [_op(1, 1 << 20)]}, 1, 1 << 20),
+}
+
+
+@pytest.fixture(params=["msgpack", "json"])
+def body_format(request, monkeypatch):
+    """Run a codec test on msgpack bodies and on the JSON fallback."""
+    from repro.transport import codec
+    if request.param == "json":
+        monkeypatch.setattr(codec, "msgpack", None)
+    elif codec.msgpack is None:
+        pytest.fail("msgpack is not installed")
+    return request.param
+
+
+@pytest.mark.parametrize("shape", sorted(FANOUT_SHAPES))
+def test_codec_fanout_frames_are_encode_msg_frames(shape, body_format):
+    """A broadcast's frames, encoded once for every destination, are byte
+    for byte the frames ``encode_msg`` gives each destination alone."""
+    from repro.transport.codec import encode_fanout
+    msg = FANOUT_SHAPES[shape]()
+    dsts = [1, 3, 8, 0, 127, 128, 70000]
+    frames = encode_fanout(msg, dsts)
+    assert frames == [encode_msg(Msg(msg.kind, msg.src, d, msg.payload,
+                                     msg.size_ops, msg.size_bytes))
+                      for d in dsts]
+    assert decode_body(split_frames(frames[2])[0][0]).dst == 8
+
+
+def test_codec_fanout_refuses_oversize_body(body_format):
+    from repro.transport.codec import MAX_FRAME, encode_fanout
+    big = "x" * (MAX_FRAME + 16)
+    with pytest.raises(ValueError, match="exceeds MAX_FRAME"):
+        encode_fanout(Msg("blob", 0, -1, {"v": big}, 1), [1, 2])
+
+
+# ---------------------------------------------------------------------------
+# engine facade (stub senders, no sockets)
+# ---------------------------------------------------------------------------
+
+class _Sink:
+    """A replica that records what is delivered to it."""
+
+    def __init__(self, node_id):
+        self.node_id = node_id
+        self.got = []
+
+    def on_message(self, msg, now):
+        self.got.append((msg.kind, msg.src, msg.dst, msg.payload))
+
+
+def _facade(broadcast: bool, kind: str):
+    """Send one message to replicas 1-3 (2 has no route), to itself and to
+    client 9, as one broadcast or as single sends, from replica 0 of a
+    4-replica facade; what each stub sender and the local replica got.
+    The local replica gets the payload object itself."""
+    import asyncio
+
+    from repro.core.simulator import Node
+    from repro.transport.net import NetContext
+
+    async def go():
+        ctx = NetContext(0, 4, epoch=time.time())
+        sink = _Sink(0)
+        ctx.add_node(sink)
+        ctx.commit_log[5] = (0.5, "fast")
+        wire = {d: [] for d in (1, 3, 9)}
+        for d, got in wire.items():
+            ctx.register_peer(d, got.append)
+        node = Node(0, ctx)
+        payload = {"ops": [_op(1), _op(2)], "fb": 4, "op_ids": [5]}
+        dsts = [1, 0, 2, 3, 9]
+        if broadcast:
+            node.broadcast(dsts, kind, payload, 2)
+        else:
+            for d in dsts:
+                node.send(d, kind, payload, 2)
+        await asyncio.sleep(0)             # the deferred loopback
+        assert [m[3] for m in sink.got] == [payload]
+        return ctx, [m[:3] for m in sink.got], wire
+
+    return asyncio.run(go())
+
+
+@pytest.mark.parametrize("kind", ["fast_commit", "client_reply"])
+def test_facade_broadcast_is_its_single_posts(kind):
+    """One broadcast through the served engine counts, routes, loops back
+    and writes the same bytes per peer as one post per destination, and
+    encodes its body once (a client reply is enriched per destination, so
+    it is encoded per destination)."""
+    one, looped1, wire1 = _facade(False, kind)
+    many, looped2, wire2 = _facade(True, kind)
+    assert wire2 == wire1 and all(len(w) == 1 for w in wire1.values())
+    assert looped2 == looped1 == [(kind, 0, 0)]
+    assert (many.stats_messages, many.dropped_no_route) == \
+        (one.stats_messages, one.dropped_no_route) == (5, 1)
+    assert one.encodes == 3
+    assert many.encodes == (3 if kind == "client_reply" else 1)
+
+
 # ---------------------------------------------------------------------------
 # loopback cluster: real histories through the real checker
 # ---------------------------------------------------------------------------
@@ -145,6 +261,8 @@ def test_served_cluster_history_linearizable_and_bounded():
         assert ns["applied"] == cfg.total_ops
         assert not ns["recovering"] and not ns["isolated"]
         assert ns["read_results"] <= READ_RESULTS_CAP
+        # broadcasts are encoded once for all their destinations
+        assert 0 < ns["encodes"] < ns["messages"]
         assert ns["commit_log"] <= READ_RESULTS_CAP
         for ch in ns["channels"]:
             assert ch["queue_hwm"] <= ch["max_queue"]
