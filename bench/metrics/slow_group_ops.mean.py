@@ -1,0 +1,14 @@
+"""Ops per slow-path instance: the window's growth of the replicas' summed
+slow ops over that of their slow instances (``slow`` rows of the traced
+replicas, ``SlowCounters``). Silent where no replica recorded ``slow``
+rows, or none proposed an instance in the window."""
+
+from hostrows import window_rows
+
+
+def read(run):
+    rows = window_rows(run.node_stats, "slow", run.t0, run.t1)
+    instances = sum(r[-1, 1] - r[0, 1] for r in rows)
+    if not instances:
+        return None
+    return sum(r[-1, 2] - r[0, 2] for r in rows) / instances

@@ -13,7 +13,14 @@ as written in Algorithm 2 — this is what makes the leader the bottleneck
 the paper measures, and it is shared by the Cabinet baseline (Cabinet *is*
 the slow path applied to every operation). Queued forwards are merged into
 one instance up to ``group_cap`` ops (the paper's "dynamic reordering of
-non-conflicting operations within the same batch").
+non-conflicting operations within the same batch"). Every run sets
+``group_cap`` to the client batch size (``transport/node_runner.serve``,
+``scenario/build._run_flat``, ``shard/runner.build_group``), so queued
+forwards of whole batches never merge: an instance carries one client
+batch.
+
+``SlowCounters`` count what the serialized instances cost on the replica
+that proposes them. They take no simulated time and no rng draw.
 
 Cross-path ordering: each slow op's SLOW_COMMIT carries the op_ids of fast
 ops that were live at the leader when the instance formed; replicas apply
@@ -30,6 +37,14 @@ from collections import deque
 from typing import Dict, List, Optional
 
 from repro.core.simulator import Msg, Op
+
+
+@dataclasses.dataclass(slots=True)
+class SlowCounters:
+    instances: int = 0      # instances proposed
+    ops: int = 0            # ops in them
+    hold_s: float = 0.0     # propose -> mutex release, summed
+    backlog: int = 0        # batches left queued at each propose, summed
 
 
 @dataclasses.dataclass(eq=False, slots=True)
@@ -57,6 +72,7 @@ class SlowPathMixin:
         self._inst_seq = itertools.count()
         self._forwarded: Dict[int, Op] = {}        # op_id -> op (retransmit)
         self._slow_pending: set = set()            # op_ids queued or proposed
+        self.slow_counters = SlowCounters()
 
     # -- leader-side pending bookkeeping (also feeds fast-path conflicts) -----
 
@@ -218,6 +234,10 @@ class SlowPathMixin:
         while (self.slow_queue
                and len(ops) + len(self.slow_queue[0]) <= self.group_cap):
             ops.extend(self.slow_queue.popleft())
+        counters = self.slow_counters
+        counters.instances += 1
+        counters.ops += len(ops)
+        counters.backlog += len(self.slow_queue)
         self.sim.busy(self.node_id, self._coord_cost * len(ops))
         # cross-path deps: fast ops live at the leader for these objects
         # must apply first, everywhere (leader in_flight holds fast entries
@@ -360,6 +380,7 @@ class SlowPathMixin:
         self._apply_slow_commit(inst.ops, inst.deps, now)
         self.slow_inst = None
         self.slow_mutex = False                     # unlock(mutex)
+        self.slow_counters.hold_s += now - inst.propose_time
         self._slow_kick(now)
 
     def on_slow_nack(self, msg: Msg, now: float) -> None:
@@ -375,6 +396,7 @@ class SlowPathMixin:
             inst.timer.cancel()
         self.slow_inst = None
         self.slow_mutex = False
+        self.slow_counters.hold_s += now - inst.propose_time
         for op in inst.ops:
             self._slow_pending_remove(op)
         self.forward_slow(inst.ops, now)

@@ -20,8 +20,11 @@ On SIGTERM/SIGINT the runner dumps its raw tracer events to
 traces through the same ``canonical_events`` path simulator runs use.
 
 ``stats.json`` holds the engine's counters (``messages``, ``encodes``,
-``applied``, ``recovering``, ``isolated``, ...) and each outbound channel's
-(``sent``, ``wait_s``, ``dropped``, ``reconnects``, ``queue_hwm``, ...).
+``applied``, ``recovering``, ``isolated``, ...), the slow path's whole-run
+``SlowCounters`` (``slow_instances``, ``slow_ops``, ``slow_hold_s``,
+``slow_backlog``; a protocol without the slow path has none) and each
+outbound channel's (``sent``, ``wait_s``, ``dropped``, ``reconnects``,
+``queue_hwm``, ...).
 With ``--trace`` the replica's host is sampled too, every row stamped on
 the cluster clock (``NetContext.now``) so a reader can cut any window
 out of it:
@@ -31,7 +34,9 @@ out of it:
   * ``host``: ``[t, cpu_s, frames_sent, chan_wait_s]`` every 0.25 s —
     the process's CPU time (``time.process_time``, all threads), and the
     frames written and their summed queue wait over every outbound
-    channel (``PeerChannel(timed=True)``).
+    channel (``PeerChannel(timed=True)``);
+  * ``slow``: ``[t, instances, ops, hold_s, backlog]`` with each ``host``
+    row, the slow path's counters so far (none without the slow path).
 
 These rows grow with the run's length (about 100 a second), not with
 its op count; an untraced replica records none.
@@ -46,6 +51,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import dataclasses
 import json
 import os
 import signal
@@ -157,8 +163,11 @@ async def serve(args) -> None:
     if args.recover:
         replica.on_recover(ctx.now)
 
+    slow = getattr(replica, "slow_counters", None)
     host = {"loop_lag": [], "host": []} if args.trace else {}
-    sampler = (asyncio.ensure_future(sample_host(ctx, channels, host))
+    if host and slow is not None:
+        host["slow"] = []
+    sampler = (asyncio.ensure_future(sample_host(ctx, channels, host, slow))
                if host else None)
 
     stop = asyncio.Event()
@@ -175,8 +184,10 @@ async def serve(args) -> None:
     _dump(ctx, replica, channels, run_dir, args.node_id, host)
 
 
-async def sample_host(ctx: NetContext, channels, rows: dict) -> None:
-    """Append ``loop_lag`` and ``host`` rows (module docstring) until
+async def sample_host(ctx: NetContext, channels, rows: dict,
+                      slow) -> None:
+    """Append ``loop_lag``, ``host`` and, where the replica has
+    ``SlowCounters`` (``slow``), ``slow`` rows (module docstring) until
     cancelled."""
     next_host = ctx.now
     while True:
@@ -189,6 +200,9 @@ async def sample_host(ctx: NetContext, channels, rows: dict) -> None:
             rows["host"].append([now, time.process_time(),
                                  sum(c.sent for c in channels),
                                  sum(c.wait_s for c in channels)])
+            if slow is not None:
+                rows["slow"].append([now, slow.instances, slow.ops,
+                                     slow.hold_s, slow.backlog])
             next_host = now + HOST_PERIOD_S
 
 
@@ -210,6 +224,7 @@ def _dump(ctx: NetContext, replica, channels, run_dir: Path,
         "read_results": len(ctx.read_results),
         "recovering": replica.recovering,
         "isolated": replica._isolated,
+        **_slow_totals(replica),
         "channels": [c.stats() for c in channels],
         "jax_backend": jax_backend_started(),
         **host,
@@ -217,6 +232,13 @@ def _dump(ctx: NetContext, replica, channels, run_dir: Path,
     tmp = run_dir / f".node-{node_id}.stats.json.tmp"
     tmp.write_text(json.dumps(stats, indent=1))
     os.replace(tmp, run_dir / f"node-{node_id}.stats.json")
+
+
+def _slow_totals(replica) -> dict:
+    slow = getattr(replica, "slow_counters", None)
+    if slow is None:
+        return {}
+    return {f"slow_{k}": v for k, v in dataclasses.asdict(slow).items()}
 
 
 def main(argv=None) -> None:

@@ -8,7 +8,9 @@ the shapes the system evaluates:
                  2 clients x 5 in flight x batch 10, on 5 replicas;
   * (100, 9):    the same batch at the 9-replica server-scaling width;
   * (40000, 5):  one call holding every fast-path instance of a full
-                 ``paper_default`` run.
+                 ``paper_default`` run;
+  * (8192, 5):   one chunk of the chip benchmark's post-window fold
+                 (``bench/fold.py``) on the 5-replica cell.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every test worker
@@ -17,7 +19,7 @@ imports this file.
 
 import pytest
 
-SHAPES = [(100, 5), (100, 9), (40_000, 5)]
+SHAPES = [(100, 5), (100, 9), (40_000, 5), (8192, 5)]
 
 
 @pytest.fixture(scope="module")

@@ -293,6 +293,24 @@ def test_served_cluster_history_linearizable_and_bounded():
         assert host[-1][2] <= sum(ch["sent"] for ch in ns["channels"])
         assert sum(ch["wait_s"] for ch in ns["channels"]) > 0
 
+    # the slow path's counters: whole-run totals in every replica's
+    # stats, and rows sampled with the host rows on the cluster clock;
+    # the leader counts every op committed on the slow path
+    slow_committed = sum(v for k, v in counters.items()
+                         if k.startswith("ops_committed_total")
+                         and "slow" in k)
+    assert slow_committed > 0
+    assert sum(ns["slow_ops"] for ns in r.node_stats) == slow_committed
+    for ns in r.node_stats:
+        rows = ns["slow"]
+        assert [row[0] for row in rows] == [row[0] for row in ns["host"]]
+        assert all(len(row) == 5 for row in rows)
+        totals = [ns["slow_instances"], ns["slow_ops"], ns["slow_hold_s"],
+                  ns["slow_backlog"]]
+        assert all(x <= total for x, total in zip(rows[-1][1:], totals))
+        assert ns["slow_ops"] <= ns["slow_instances"] * cfg.batch_size
+        assert ns["slow_hold_s"] >= 0 and ns["slow_backlog"] >= 0
+
 
 # ---------------------------------------------------------------------------
 # crash + recovery over sockets
