@@ -146,15 +146,18 @@ class BaseReplica(Node):
     HB_TIMEOUT = 45e-3
 
     def __init__(self, node_id: int, sim: Simulation, *, t_fail: int,
-                 steepness: Optional[float] = None, group_cap: int = 64,
+                 steepness: Optional[float] = None,
+                 group_cap: Optional[int] = 64,
                  leases=None, reassign=None, coding=None):
         super().__init__(node_id, sim)
         n = sim.n
         self.t_fail = t_fail
         # slow-path group-commit cap: one consensus instance carries at most
-        # this many ops (= the experiment's client batch size, so Cabinet's
-        # per-client-batch instances and WOC's merged forwards amortize the
-        # leader round identically — "reordering ... within the same batch")
+        # this many ops. Simulated runs set the client batch size, so
+        # Cabinet's per-client-batch instances and WOC's merged forwards
+        # amortize the leader round identically ("reordering ... within the
+        # same batch"); served replicas set None and bound an instance by
+        # the frame limit instead (core/slowpath.py)
         self.group_cap = group_cap
         self.r = steepness if steepness is not None else W.solve_steepness(
             n, max(1, min(t_fail, (n - 1) // 2)))

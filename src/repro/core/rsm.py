@@ -16,6 +16,7 @@ key-value store and records the per-object apply sequence. Tests use:
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 from collections import defaultdict
 from typing import Dict, List, Sequence, Tuple
 
@@ -138,6 +139,16 @@ def check_state_machine_safety(rsms: Sequence[RSM]) -> Tuple[bool, str]:
                 return False, (f"divergent apply order on object {obj}: "
                                f"{s[:8]} vs {longest[:8]}")
     return True, "ok"
+
+
+def apply_digest(rsm: RSM) -> str:
+    """A digest of the per-object sequences of applied write values: two
+    replicas that applied the same writes have the same digest exactly
+    when each object's writes applied in the same order (the served twin
+    of :func:`check_state_machine_safety`, for replicas in other
+    processes)."""
+    return hashlib.blake2b(repr(sorted(rsm.applied.items())).encode(),
+                           digest_size=16).hexdigest()
 
 
 @dataclasses.dataclass(frozen=True)

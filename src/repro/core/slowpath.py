@@ -11,13 +11,21 @@
 The mutex serializes slow-path instances (one in flight at a time) exactly
 as written in Algorithm 2 — this is what makes the leader the bottleneck
 the paper measures, and it is shared by the Cabinet baseline (Cabinet *is*
-the slow path applied to every operation). Queued forwards are merged into
-one instance up to ``group_cap`` ops (the paper's "dynamic reordering of
-non-conflicting operations within the same batch"). Every run sets
-``group_cap`` to the client batch size (``transport/node_runner.serve``,
-``scenario/build._run_flat``, ``shard/runner.build_group``), so queued
-forwards of whole batches never merge: an instance carries one client
-batch.
+the slow path applied to every operation). When the leader takes the
+mutex it merges queued batches into one instance, in queue order, whole
+batches only (group commit):
+
+* served replicas (``transport/node_runner.serve``) merge everything
+  queued, up to the largest instance whose SLOW_PROPOSE and SLOW_COMMIT
+  frames fit under the transport's frame limit (``frame_fits``,
+  ``codec.slow_instance_fits``). Each instance costs the leader the same
+  frames, handler calls and timer whatever its size, so its one event
+  loop amortizes them over every batch that waited for the mutex;
+* simulated runs (``scenario/build._run_flat``, ``shard/runner.build_group``)
+  cap an instance at ``group_cap`` = the client batch size, so an instance
+  carries one client batch, as Alg. 2 is modelled in the paper-figure
+  reproductions. Their fault-free timing is pinned by golden traces, and
+  the Cabinet baseline shares this path.
 
 ``SlowCounters`` count what the serialized instances cost on the replica
 that proposes them. They take no simulated time and no rng draw.
@@ -34,7 +42,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 from repro.core.simulator import Msg, Op
 
@@ -73,6 +81,9 @@ class SlowPathMixin:
         self._forwarded: Dict[int, Op] = {}        # op_id -> op (retransmit)
         self._slow_pending: set = set()            # op_ids queued or proposed
         self.slow_counters = SlowCounters()
+        # served replicas: whether an instance of (ops, dep ids) encodes
+        # its frames under the transport's limit (module docstring)
+        self.frame_fits: Optional[Callable[[int, int], bool]] = None
 
     # -- leader-side pending bookkeeping (also feeds fast-path conflicts) -----
 
@@ -228,27 +239,12 @@ class SlowPathMixin:
                           size_bytes=sum(op.size for op in ops))
             return
         self.slow_mutex = True                      # lock(mutex)
-        # group commit: merge queued forwards into one instance, up to the
-        # configured cap (always take the head group)
-        ops = list(self.slow_queue.popleft())
-        while (self.slow_queue
-               and len(ops) + len(self.slow_queue[0]) <= self.group_cap):
-            ops.extend(self.slow_queue.popleft())
+        ops, deps = self._slow_group()
         counters = self.slow_counters
         counters.instances += 1
         counters.ops += len(ops)
         counters.backlog += len(self.slow_queue)
         self.sim.busy(self.node_id, self._coord_cost * len(ops))
-        # cross-path deps: fast ops live at the leader for these objects
-        # must apply first, everywhere (leader in_flight holds fast entries
-        # only — slow ops are tracked in _slow_pending)
-        deps: Dict[int, List[int]] = {}
-        for op in ops:
-            live = [x for x in self.in_flight.get(op.obj, {})
-                    if x != op.op_id and x not in self._slow_pending
-                    and x not in self.rsm.applied_ops]
-            if live:
-                deps[op.op_id] = live
         w = self.node_weights()                     # getPriorities()
         inst = SlowInstance(inst_id=next(self._inst_seq)
                             | (self.node_id << 48),
@@ -288,6 +284,45 @@ class SlowPathMixin:
                                     "slow_inst_timeout",
                                     {"inst": inst.inst_id})
         self._slow_check_commit(inst, now)
+
+    def _slow_group(self):
+        """Group commit: pop the next instance's ops off ``slow_queue`` with
+        their cross-path deps. The head batch always, then each queued
+        batch in order while the instance stays within ``group_cap`` ops
+        (None: no cap) and ``frame_fits(ops, dep ids)`` (None: no frame
+        bound)."""
+        queue = self.slow_queue
+        cap = self.group_cap
+        fits = self.frame_fits
+        ops = list(queue.popleft())
+        deps = self._slow_deps(ops)
+        n_ids = sum(map(len, deps.values()))
+        while queue:
+            batch = queue[0]
+            n = len(ops) + len(batch)
+            if cap is not None and n > cap:
+                break
+            more = self._slow_deps(batch)
+            m = n_ids + sum(map(len, more.values()))
+            if fits is not None and not fits(n, m):
+                break
+            ops.extend(queue.popleft())
+            deps.update(more)
+            n_ids = m
+        return ops, deps
+
+    def _slow_deps(self, ops: List[Op]) -> Dict[int, List[int]]:
+        """Cross-path deps: fast ops live at the leader for these objects
+        must apply first, everywhere (leader in_flight holds fast entries
+        only — slow ops are tracked in _slow_pending)."""
+        deps: Dict[int, List[int]] = {}
+        for op in ops:
+            live = [x for x in self.in_flight.get(op.obj, {})
+                    if x != op.op_id and x not in self._slow_pending
+                    and x not in self.rsm.applied_ops]
+            if live:
+                deps[op.op_id] = live
+        return deps
 
     def on_slow_accept(self, msg: Msg, now: float) -> None:
         inst = self.slow_inst

@@ -48,6 +48,14 @@ MAX_FRAME = 64 * 1024 * 1024      # sanity bound: a snapshot of a long soak
 
 _TAGS = ("__op__", "__set__", "__tup__", "__map__")
 
+# Upper bounds, in either body format, on what a slow-path instance puts
+# in its SLOW_PROPOSE and SLOW_COMMIT bodies: per op (its tagged record,
+# every field a 64-bit number or a short tag, and its key in the commit's
+# deps map), per dep id, and the rest of the body.
+SLOW_OP_BYTES = 256
+SLOW_ID_BYTES = 24
+SLOW_BODY_BYTES = 256
+
 
 def _enc(x):
     t = type(x)
@@ -101,6 +109,14 @@ def _check_body(n: int, msg: Msg) -> None:
             f"encoded frame body is {n} bytes, exceeds MAX_FRAME "
             f"({MAX_FRAME}): refusing to emit an undecodable frame "
             f"(kind={msg.kind!r}, size_ops={msg.size_ops})")
+
+
+def slow_instance_fits(n_ops: int, n_ids: int) -> bool:
+    """Whether a slow-path instance of ``n_ops`` ops, whose deps list
+    ``n_ids`` ids in all, encodes its propose and commit bodies within
+    ``MAX_FRAME``: the served leader's group-commit bound."""
+    return (SLOW_BODY_BYTES + n_ops * SLOW_OP_BYTES
+            + n_ids * SLOW_ID_BYTES) <= MAX_FRAME
 
 
 def encode_msg(msg: Msg) -> bytes:

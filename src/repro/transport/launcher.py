@@ -144,7 +144,6 @@ class ClusterLauncher:
                "--node-id", str(node_id), "--n", str(cfg.n_replicas),
                "--run-dir", str(self.run_dir), "--protocol", cfg.protocol,
                "--seed", str(cfg.seed), "--epoch", repr(self.epoch),
-               "--batch-size", str(cfg.batch_size),
                "--t-fail", str(cfg.t_fail),
                "--max-queue", str(cfg.max_queue),
                "--hb-scale", str(cfg.hb_scale)]

@@ -25,9 +25,10 @@ traces through the same ``canonical_events`` path simulator runs use.
 ``slow_backlog``; a protocol without the slow path has none) and each
 outbound channel's (``sent``, ``wait_s``, ``dropped``, ``reconnects``,
 ``queue_hwm``, ...).
-With ``--trace`` the replica's host is sampled too, every row stamped on
-the cluster clock (``NetContext.now``) so a reader can cut any window
-out of it:
+With ``--trace`` it also holds ``apply_digest`` (``core/rsm.py``), equal
+on replicas that applied each object's writes in the same order, and the
+replica's host is sampled, every row stamped on the cluster clock
+(``NetContext.now``) so a reader can cut any window out of it:
 
   * ``loop_lag``: ``[t, lag_s]`` each time a 10 ms sleep wakes, ``lag_s``
     being how much later than asked it woke;
@@ -59,8 +60,10 @@ import sys
 import time
 from pathlib import Path
 
+from repro.core.rsm import apply_digest
 from repro.scenario.registry import protocol_class
-from repro.transport.codec import decode_body, decode_hello, read_frame
+from repro.transport.codec import (decode_body, decode_hello, read_frame,
+                                   slow_instance_fits)
 from repro.transport.net import NetContext, PeerChannel
 
 LAG_PERIOD_S = 0.01         # the loop-lag probe's sleep
@@ -115,16 +118,25 @@ async def _serve_connection(ctx: NetContext, reader, writer) -> None:
         writer.close()
 
 
+def served_replica(protocol: str, node_id: int, ctx: NetContext,
+                   t_fail: int):
+    """The replica a served process runs: its slow-path instances carry
+    every batch queued at the leader, bounded by the frame limit
+    (``core/slowpath.py``) and not by a batch count."""
+    t = max(1, min(t_fail, (ctx.n - 1) // 2))
+    replica = protocol_class(protocol)(node_id, ctx, t_fail=t,
+                                       group_cap=None)
+    replica.frame_fits = slow_instance_fits
+    return replica
+
+
 async def serve(args) -> None:
     run_dir = Path(args.run_dir)
     ctx = NetContext(args.node_id, args.n, epoch=args.epoch, seed=args.seed)
     if args.trace:
         from repro.obs.spans import Tracer
         ctx.tracer = Tracer(sample_every=args.sample_every)
-    cls = protocol_class(args.protocol)
-    t = max(1, min(args.t_fail, (args.n - 1) // 2))
-    replica = cls(args.node_id, ctx, t_fail=t,
-                  group_cap=max(args.batch_size, 1))
+    replica = served_replica(args.protocol, args.node_id, ctx, args.t_fail)
     # failure-detector timescale: the class constants assume the
     # simulator's perfectly fair scheduler; real processes on a loaded
     # host see multi-hundred-ms event-loop stalls (GC, CPU contention,
@@ -229,6 +241,8 @@ def _dump(ctx: NetContext, replica, channels, run_dir: Path,
         "jax_backend": jax_backend_started(),
         **host,
     }
+    if ctx.tracer is not None:
+        stats["apply_digest"] = apply_digest(replica.rsm)
     tmp = run_dir / f".node-{node_id}.stats.json.tmp"
     tmp.write_text(json.dumps(stats, indent=1))
     os.replace(tmp, run_dir / f"node-{node_id}.stats.json")
@@ -252,7 +266,6 @@ def main(argv=None) -> None:
                    help="cluster-wide time.time() origin: every process "
                         "reports 'now' relative to it, so merged spans "
                         "and histories share one timeline")
-    p.add_argument("--batch-size", type=int, default=8)
     p.add_argument("--t-fail", type=int, default=1)
     p.add_argument("--max-queue", type=int, default=512)
     p.add_argument("--hb-scale", type=float, default=10.0,

@@ -1,9 +1,9 @@
 """The slow path's instance counters (``repro.core.slowpath.SlowCounters``)
 on simulated runs at full contention: the leader counts every op it
 committed on the slow path, each instance holds at most ``group_cap`` ops,
-and the mutex's holds never overlap. The counters take no simulated time
-and no rng draw; the golden pins of ``test_scenario.py`` hold them to
-that."""
+one client batch, and the mutex's holds never overlap. The counters take
+no simulated time and no rng draw; the golden pins of ``test_scenario.py``
+hold them to that."""
 
 from collections import Counter
 
@@ -32,6 +32,10 @@ def test_counters_count_the_leaders_slow_instances(protocol):
     assert len(sizes) == leader.instances
     assert sum(sizes.values()) == leader.ops
     assert max(sizes.values()) <= art.replicas[0].group_cap
+    # simulated runs keep one client batch per instance (the served
+    # replicas alone merge queued batches: tests/test_slow_group_commit.py)
+    assert art.replicas[0].group_cap == sc.batch_size
+    assert max(sizes.values()) <= sc.batch_size
     # one instance at a time: the holds fit inside the run, end to end
     assert 0.0 < leader.hold_s <= art.sim.now
     assert leader.backlog >= 0

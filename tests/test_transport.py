@@ -105,6 +105,32 @@ def test_codec_oversize_frames_rejected_both_ends():
         encode_msg(Msg("blob", 0, 1, {"v": big}, 1))
 
 
+def test_codec_slow_instance_bound_covers_the_widest_op(body_format):
+    """``slow_instance_fits`` bounds the bodies of the widest slow
+    instance it admits: every number of each op and of the envelope at
+    64 bits, and the commit's deps listing every id."""
+    from repro.transport import codec
+    big = -(1 << 63)
+    wide = Op(big, big, big, "w", big, -1.2345678901234567e+300,
+              -1.2345678901234567e-300, "slow", big, big)
+    n_ops, n_ids = 40, 60
+    ops = [wide] * n_ops
+    deps = {big + i: [big] * (n_ids // n_ops + (i < n_ids % n_ops))
+            for i in range(n_ops)}
+    bodies = [
+        Msg("slow_propose", big, big, {"inst": big, "ops": ops,
+                                        "epoch": big}, big, big),
+        Msg("slow_commit", big, big, {"ops": ops, "deps": deps}, big, big),
+    ]
+    need = max(len(encode_msg(m)) - codec.HEADER.size for m in bodies)
+    bound = (codec.SLOW_BODY_BYTES + n_ops * codec.SLOW_OP_BYTES
+             + n_ids * codec.SLOW_ID_BYTES)
+    assert need <= bound
+    assert codec.slow_instance_fits(n_ops, n_ids) == \
+        (bound <= codec.MAX_FRAME)
+    assert not codec.slow_instance_fits(codec.MAX_FRAME, 0)
+
+
 def _op(i, size=0):
     return Op(i, 5, 0x2000000000000000 + i, "w", 1234 + i, 0.5, -1.0, "",
               None, size)
@@ -310,6 +336,33 @@ def test_served_cluster_history_linearizable_and_bounded():
         assert all(x <= total for x, total in zip(rows[-1][1:], totals))
         assert ns["slow_ops"] <= ns["slow_instances"] * cfg.batch_size
         assert ns["slow_hold_s"] >= 0 and ns["slow_backlog"] >= 0
+
+
+def test_served_full_contention_merges_slow_instances():
+    """Every op on one of 4 hot objects, so the leader's slow path does all
+    the work with many batches queued behind its mutex: the history is
+    linearizable, every replica applied each object's writes in the same
+    order, and the leader's instances carry more than one client batch
+    on average, so the served group commit engaged."""
+    cfg = ClusterConfig(n_replicas=5, n_clients=3, total_ops=1200,
+                        batch_size=4, max_inflight=8, p_hot=1.0,
+                        p_common=0.0, seed=17, time_limit_s=45)
+    r = run_served(cfg).result
+
+    assert r.clients_done == cfg.n_clients
+    assert r.committed_ops == cfg.total_ops
+    ok, why = check_history_linearizable(r.history)
+    assert ok, why
+
+    assert len(r.node_stats) == cfg.n_replicas
+    assert all(ns["applied"] == cfg.total_ops for ns in r.node_stats)
+    assert len({ns["apply_digest"] for ns in r.node_stats}) == 1
+
+    leaders = [ns for ns in r.node_stats if ns["slow_instances"]]
+    assert len(leaders) == 1
+    leader = leaders[0]
+    assert leader["slow_ops"] > cfg.total_ops // 2
+    assert leader["slow_ops"] > leader["slow_instances"] * cfg.batch_size
 
 
 # ---------------------------------------------------------------------------
