@@ -27,6 +27,7 @@ import dataclasses
 from typing import Dict, Optional
 
 from repro.coding import rs
+from repro.core import quorum as Q
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,7 +71,7 @@ def choose_plan(rep, cfg, op, now: float) -> Optional[StripePlan]:
     acc = float(w[rep.node_id])
     for r in healthy:
         acc += float(w[r])
-    if acc <= rep.obj_weights.threshold_for(op.obj):
+    if not Q.crosses(acc, rep.obj_weights.threshold_for(op.obj)):
         return None
     # link-health EMA ordering: data shards (index < k, the ones every
     # reader wants first) ride the fastest links

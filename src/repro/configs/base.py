@@ -76,7 +76,7 @@ class ModelConfig:
     @property
     def use_pallas(self) -> bool:
         return False    # CPU container: ref path; kernels validated in
-                        # interpret mode (see repro.kernels)
+                        # interpret mode (see repro.models.kernels)
 
     def dtype(self):
         return jnp.dtype(self.compute_dtype)

@@ -22,6 +22,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro.core import quorum as Q
 from repro.core import weights as W
 
 
@@ -38,10 +39,12 @@ class CheckpointConsensus:
         self.n = n_hosts
         if steepness is None:
             steepness = (W.solve_steepness(
-                n_hosts, max(1, min(t_fail, (n_hosts - 1) // 2)))
+                n_hosts, W.clamp_faults(n_hosts, t_fail))
                 if n_hosts >= 3 else 1.5)
-        self.weights = np.asarray(W.geometric_weights(n_hosts, steepness))
-        self.threshold = float(self.weights.sum()) / 2.0
+        # float64, as GradQuorum: at fleet sizes a float32 base decides
+        # sums within its rounding of T differently from exact arithmetic
+        self.weights = W.geometric_weights_np(n_hosts, steepness, np.float64)
+        self.threshold = float(Q.consensus_threshold(self.weights))
         self.pending: Dict[int, PendingCommit] = {}
         self.committed_step: int = -1
 
@@ -56,7 +59,7 @@ class CheckpointConsensus:
             return False
         p.acked[host] = True
         w = sum(self.weights[h] for h in p.acked)
-        if w > self.threshold and step > self.committed_step:
+        if Q.crosses(w, self.threshold) and step > self.committed_step:
             self.committed_step = step
             return True
         return False
@@ -72,7 +75,7 @@ class CheckpointConsensus:
     def write_manifest(self, directory, step: int) -> pathlib.Path:
         path = pathlib.Path(directory) / f"manifest_{step:08d}.json"
         cert = self.certificate(step)
-        cert["committed"] = cert["weight"] > cert["threshold"]
+        cert["committed"] = Q.crosses(cert["weight"], cert["threshold"])
         path.write_text(json.dumps(cert, indent=2))
         return path
 
@@ -84,8 +87,8 @@ class CheckpointConsensus:
                 m = json.loads(p.read_text())
             except (OSError, json.JSONDecodeError):
                 continue
-            if m.get("committed") and m.get("weight", 0) > m.get(
-                    "threshold", float("inf")):
+            if m.get("committed") and Q.crosses(
+                    m.get("weight", 0), m.get("threshold", float("inf"))):
                 if best is None or m["step"] > best["step"]:
                     best = m
         return best

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import quorum as Q
 from repro.core import weights as W
 
 
@@ -46,16 +47,11 @@ class QuorumState:
     committed_frac: float = 1.0
 
     def weights(self) -> np.ndarray:
-        order = np.argsort(self.latency_ema, kind="stable")
-        ranks = np.empty_like(order)
-        ranks[order] = np.arange(len(order))
-        # float64 + max-normalized exponents: at fleet sizes (n > ~50) the
-        # f32 geometric series loses the light tail entirely and strict
-        # majority checks break on precision
-        n = len(order)
-        expo = np.arange(n - 1, -1, -1, dtype=np.float64) - (n - 1)
-        base = np.power(np.float64(self.steepness), expo)
-        return base[ranks]
+        # float64: at fleet sizes (n > ~50) a float32 base loses the light
+        # tail entirely and strict majority checks break on precision
+        base = W.geometric_weights_np(len(self.latency_ema), self.steepness,
+                                      np.float64)
+        return base[W.latency_ranks(self.latency_ema)]
 
 
 class GradQuorum:
@@ -66,8 +62,8 @@ class GradQuorum:
     def __init__(self, n_workers: int, *, t_fail: int = 1,
                  decay: float = 0.9):
         self.n = n_workers
-        r = W.solve_steepness(n_workers, max(1, min(
-            t_fail, (n_workers - 1) // 2))) if n_workers >= 3 else 1.5
+        r = W.solve_steepness(n_workers, W.clamp_faults(
+            n_workers, t_fail)) if n_workers >= 3 else 1.5
         self.state = QuorumState(
             latency_ema=np.full(n_workers, 1.0), steepness=r, decay=decay)
 
@@ -85,12 +81,8 @@ class GradQuorum:
         the quorum in arrival order until weight strictly exceeds T.
         """
         t = self.state.latency_ema if arrivals is None else arrivals
-        w = self.state.weights()
         order = np.argsort(t, kind="stable")
-        csum = np.cumsum(w[order])
-        thresh = w.sum() / 2.0
-        k = int(np.searchsorted(csum, thresh, side="right")) + 1
-        k = min(k, self.n)
+        k = Q.quorum_size(self.state.weights(), order)
         mask = np.zeros(self.n, bool)
         mask[order[:k]] = True
         self.state.committed_frac = k / self.n
@@ -119,7 +111,7 @@ class GradQuorum:
         w = self.state.weights()
         return {"step": step, "committed": mask.tolist(),
                 "weight": float(w[mask].sum()),
-                "threshold": float(w.sum() / 2.0),
+                "threshold": float(Q.consensus_threshold(w)),
                 "frac": self.state.committed_frac}
 
     # ---- analytics: expected step-time win (order statistics) --------------
@@ -134,16 +126,13 @@ class GradQuorum:
         """
         rng = np.random.default_rng(seed)
         w = self.state.weights()
-        thresh = w.sum() / 2.0
         full, quorum = [], []
         for _ in range(trials):
             t = latency_dist * (0.7 + 0.6 * rng.random(self.n)) \
                 + rng.exponential(0.1 * latency_dist)
             full.append(t.max())
             order = np.argsort(t)
-            csum = np.cumsum(w[order])
-            k = int(np.searchsorted(csum, thresh, side="right")) + 1
-            quorum.append(t[order[min(k, self.n) - 1]])
+            quorum.append(t[order[Q.quorum_size(w, order) - 1]])
         return {"barrier_mean_s": float(np.mean(full)),
                 "quorum_mean_s": float(np.mean(quorum)),
                 "speedup": float(np.mean(full) / np.mean(quorum))}

@@ -21,6 +21,7 @@ from typing import Dict, List
 
 import numpy as np
 
+from repro.core import quorum as Q
 from repro.core.protocol_base import BaseReplica
 from repro.core.simulator import Msg, Op, Simulation
 
@@ -45,7 +46,7 @@ class EPaxosReplica(BaseReplica):
         super().__init__(node_id, sim, t_fail=t_fail, steepness=1.0, **kw)
         self.batches: Dict[int, EpaxosBatch] = {}
         self._seq = itertools.count()
-        self.majority = sim.n // 2 + 1
+        self.majority = Q.count_majority(sim.n)
 
     # -- coordinator -------------------------------------------------------------
 

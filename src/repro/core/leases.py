@@ -69,6 +69,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, Optional, Set
 
+from repro.core import quorum as Q
+
 
 @dataclasses.dataclass(frozen=True)
 class LeaseConfig:
@@ -165,16 +167,11 @@ class LeaseManager:
         self._ll_renew_at = -1.0
         # k_max: the largest k whose top-k base weights can NOT strictly
         # cross T^N — promises from the other n-1-k_max peers make a
-        # usurper quorum impossible (the leader itself nacks usurpers)
-        base = rep.obj_weights.base
-        half = rep.obj_weights.half_sum
-        k, s = 0, 0.0
-        for w in base:                           # descending by rank
-            if s + float(w) > half:
-                break
-            s += float(w)
-            k += 1
-        self._ll_need = max(0, rep.sim.n - 1 - k)
+        # usurper quorum impossible (the leader itself nacks usurpers).
+        # Over the geometric base and its half_sum even while quorums are
+        # flat; whether it should follow the flat fallback is open.
+        k_max = Q.quorum_size(rep.obj_weights.base) - 1
+        self._ll_need = max(0, rep.sim.n - 1 - k_max)
         # metrics (host-side)
         self.local_reads = 0
         self.grants = 0
@@ -403,7 +400,11 @@ class LeaseManager:
 
     def _round_check(self, rnd: _GrantRound, now: float) -> None:
         rep = self.rep
-        if not rnd.leader_voted or rnd.acc <= rep.obj_weights.half_sum:
+        # half_sum, not current_threshold(): while quorums are flat the
+        # votes (weight 1 each) meet the geometric threshold; whether they
+        # should meet the flat one is open
+        if not rnd.leader_voted or not Q.crosses(rnd.acc,
+                                                 rep.obj_weights.half_sum):
             return
         obj = rnd.obj
         self._finish_round(rnd)

@@ -5,10 +5,10 @@ All four protocol implementations (WOC, Cabinet, EPaxos, MultiPaxos) extend
 
   * an **in-flight map** ``obj -> {op_id: registered_time}`` with lazy
     timeout GC (Theorem 2's shared conflict-tracking state, Fig. 3),
-  * **node-weight tracking** (latency EMA -> rank -> geometric weight,
-    paper §3.1 "slow path" weights / Cabinet §2.1),
-  * **object-weight tracking** (per-object latency EMA -> geometric weight,
-    paper §3.2) backed by numpy for event-loop speed,
+  * **node and object weights**: the node-level latency EMA it updates,
+    and the :class:`repro.core.weights.ObjectWeightTable` that turns it
+    and the per-object EMAs into geometric weights (paper §3.1-3.2,
+    Cabinet §2.1),
   * a heartbeat failure detector + rank-order **leader election**
     (simplified Cabinet view change: the highest-weighted replica believed
     alive is the leader; followers only accept proposals from their current
@@ -21,124 +21,10 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro.core import quorum as Q
 from repro.core import weights as W
 from repro.core.rsm import RSM
 from repro.core.simulator import Msg, Node, Simulation
-
-
-class ObjectWeightTable:
-    """Per-object latency EMA -> geometric weights (numpy, event-loop fast).
-
-    The returned weight vectors are permutations of ``base`` and treated as
-    read-only by callers, so the node-level fallback (the common case: a
-    first-touch object has no EMA of its own) is cached and recomputed only
-    when the node EMA changes (``node_version`` is bumped by
-    ``BaseReplica.observe_node``).
-    """
-
-    def __init__(self, n: int, r: float, node_ema: np.ndarray,
-                 decay: float = 0.85):
-        self.n = n
-        # numpy twin of the jax weight kernel: the simulator path must not
-        # execute jax (worker and replica processes — see weights.py)
-        self.base = W.geometric_weights_np(n, r)           # descending by rank
-        self.half_sum = float(self.base.sum()) / 2.0
-        self.decay = decay
-        # per-object EMAs are plain float lists: element updates in
-        # ``observe`` are ~5x cheaper than numpy scalar writes, and the
-        # argsort in ``_weights_of`` converts on the (much rarer) read
-        self.ema: Dict[int, list] = {}
-        self.node_ema = node_ema  # shared fallback: node-level latency EMA
-        self.node_version = 0
-        self._nw_version = -1
-        self._nw: np.ndarray | None = None
-        self._ranks = np.empty(n, dtype=np.int64)   # scratch
-        self._arange = np.arange(n)
-        # installed weight view (repro.core.reassign): while active, the
-        # epoch-stamped ranking overrides BOTH the per-object EMAs and
-        # the node-level ranking — the view is the shared truth all
-        # replicas quorum under, private telemetry resumes on restore.
-        self.rank_of: np.ndarray | None = None
-        # flat fallback (graceful degradation): when the view-weighted
-        # heartbeat-fresh set cannot strictly cross half_sum, quorums
-        # degrade to count-majorities (weights 1, threshold n/2)
-        self.flat = False
-        self._flat_w = np.ones(n, dtype=np.float64)
-        self._flat_threshold = n / 2.0
-
-    def observe(self, obj: int, replica: int, latency: float) -> None:
-        e = self.ema.get(obj)
-        if e is None:
-            e = self.ema[obj] = self.node_ema.tolist()
-        e[replica] = self.decay * e[replica] + (1 - self.decay) * latency
-
-    def _weights_of(self, e: np.ndarray) -> np.ndarray:
-        order = np.argsort(e, kind="stable")      # fastest first
-        ranks = self._ranks
-        ranks[order] = self._arange
-        return self.base[ranks]
-
-    def view_weights(self) -> np.ndarray:
-        """Node weights under the current view, ignoring the flat
-        fallback (the fallback's own trigger test needs these)."""
-        if self._nw_version != self.node_version:
-            ro = self.rank_of
-            self._nw = self.base[ro] if ro is not None \
-                else self._weights_of(self.node_ema)
-            self._nw_version = self.node_version
-        return self._nw
-
-    def node_weights(self) -> np.ndarray:
-        """Node-level weights, cached per node-EMA/view version."""
-        if self.flat:
-            return self._flat_w
-        return self.view_weights()
-
-    def weights_for(self, obj: int) -> np.ndarray:
-        if self.flat:
-            return self._flat_w
-        if self.rank_of is not None:
-            return self.view_weights()
-        e = self.ema.get(obj)
-        if e is None:
-            return self.view_weights()
-        return self._weights_of(e)
-
-    def current_threshold(self) -> float:
-        return self._flat_threshold if self.flat else self.half_sum
-
-    def threshold_for(self, obj: int) -> float:
-        return self.current_threshold()            # T^O = sum(W^O)/2
-
-    def shared_weights(self) -> np.ndarray:
-        """Weights under the SHARED election ranking: the epoch-stamped
-        installed view when present (``view_weights`` is then exactly
-        ``base[rank_of]``, cached), else the static deployment ranking
-        (replica id == rank). Unlike ``node_weights`` this never consults
-        the private latency EMA: every node zeroes its own EMA entry and
-        so ranks ITSELF top-weight in its private view, which would let
-        two partition sides both believe they hold the weighted
-        majority. The leadership lease and the isolation detector must
-        evaluate one vector that is identical at every replica."""
-        if self.flat:
-            return self._flat_w
-        if self.rank_of is not None:
-            return self.view_weights()
-        return self.base
-
-    def set_rank_override(self, ranking) -> None:
-        """Install (or with ``None`` clear) an epoch-stamped ranking:
-        ``ranking[0]`` gets the top geometric weight. Per-object EMAs
-        are dropped either way — telemetry gathered under the previous
-        weight regime must not leak into the new one."""
-        if ranking is None:
-            self.rank_of = None
-        else:
-            ro = np.empty(self.n, dtype=np.int64)
-            ro[np.asarray(ranking, dtype=np.int64)] = self._arange
-            self.rank_of = ro
-        self.ema.clear()
-        self.node_version += 1
 
 
 class BaseReplica(Node):
@@ -147,7 +33,7 @@ class BaseReplica(Node):
 
     def __init__(self, node_id: int, sim: Simulation, *, t_fail: int,
                  steepness: Optional[float] = None,
-                 group_cap: Optional[int] = 64,
+                 group_cap: Optional[int],
                  leases=None, reassign=None, coding=None):
         super().__init__(node_id, sim)
         n = sim.n
@@ -160,7 +46,7 @@ class BaseReplica(Node):
         # the frame limit instead (core/slowpath.py)
         self.group_cap = group_cap
         self.r = steepness if steepness is not None else W.solve_steepness(
-            n, max(1, min(t_fail, (n - 1) // 2)))
+            n, W.clamp_faults(n, t_fail))
         self.rsm = RSM()
         # node-level latency EMA; initial ranking = replica id order (the
         # simulator's speed() is non-decreasing in id, and a deployment
@@ -171,7 +57,7 @@ class BaseReplica(Node):
         self.node_ema = np.array(
             [10e-3 * (1 + 0.01 * i) for i in range(n)], dtype=np.float64)
         self.node_ema[node_id] = 0.0
-        self.obj_weights = ObjectWeightTable(n, self.r, self.node_ema)
+        self.obj_weights = W.ObjectWeightTable(n, self.r, self.node_ema)
         # hot-path precomputes: this replica's speed-scaled per-op costs
         # and its broadcast peer list (both constants for the run)
         sp = sim.costs.speed(node_id)
@@ -368,7 +254,7 @@ class BaseReplica(Node):
                 # drive them.
                 fresh = [(last_hb[p], p) for p in range(n)
                          if p != me and now - last_hb[p] <= hb_to]
-                need = n // 2          # peers needed besides self
+                need = Q.count_majority(n) - 1   # peers besides self
                 if len(fresh) < need:
                     continue
                 if not need:
@@ -387,7 +273,7 @@ class BaseReplica(Node):
                 # window is when weighted support could first fall short
                 for t_p, p in fresh:
                     acc += float(sw[p])
-                    if acc > thr:
+                    if Q.crosses(acc, thr):
                         w_until = t_p + hb_to
                         break
                 if w_until is None:

@@ -1,32 +1,68 @@
-"""Vectorized weighted-quorum mathematics (paper §3.1, §4.3-4.4).
+"""The weighted-quorum rule (paper §3.1, Theorem 1), in one place.
 
-The computational hot spot of WOC is quorum formation: given, for a batch of
-operations, the time each replica's vote arrives and the weight each vote
-carries, find the earliest moment the accumulated weight crosses the
-consensus threshold ``T = sum(w)/2``.
+A set of votes is a quorum when its weight STRICTLY exceeds ``T = sum(w)/2``
+of the whole weight vector, voters or not (the threshold is a property of
+the object, not of who answers). Strict: at exactly ``sum/2`` two disjoint
+sets could both commit under ``>=`` (uniform weights, even n), and
+Theorem 1's intersection argument needs ``>``.
 
-This module is the pure-jnp implementation (and the oracle for the Pallas
-kernel in ``repro.kernels.quorum_commit``): per operation,
+Host forms, which replicas and ``repro.coord`` call:
+:func:`consensus_threshold`, :func:`crosses`, :func:`quorum_size` (the
+smallest crossing prefix in a given order) and :func:`count_majority`. The
+hot paths (``fastpath``, ``slowpath``, the isolation scan in
+``protocol_base``) keep their one comparison inline against the threshold
+``ObjectWeightTable`` hands them: a function call per vote would cost the
+leader's saturated loop.
 
-  1. sort replica vote-arrival times ascending,
-  2. gather vote weights into arrival order,
-  3. weighted prefix-sum,
-  4. first index where the prefix sum strictly exceeds T -> commit time,
-     quorum size. (Strict: at exactly T=sum/2 two disjoint vote sets could
-     both "commit" under >=, e.g. uniform weights with even n.)
-
-Non-voting replicas (crashed, timed out, or replying CONFLICT) are encoded
-with ``arrival = +inf`` so they sort to the end and never enter a quorum.
+:func:`quorum_commit` is the jnp form, batched over operations, and the
+oracle for the Pallas kernel in ``repro.kernels.quorum_commit``: sort each
+operation's votes by arrival, prefix-sum their weights, take the first
+strict crossing -> commit time, quorum size. Non-voting replicas (crashed,
+timed out, or replying CONFLICT) arrive at ``+inf`` and never enter a quorum.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 INF = jnp.inf
+
+
+def consensus_threshold(weights):
+    """``T = sum(w)/2`` over the last axis (paper §3.1). Takes numpy or jnp
+    arrays and returns the same kind; halving is exact, so a float32 vector
+    gives its float32 sum over two."""
+    return weights.sum(axis=-1) / 2.0
+
+
+def crosses(weight, threshold):
+    """Theorem 1's strict crossing: ``weight > threshold``, never ``>=``.
+    Elementwise on arrays."""
+    return weight > threshold
+
+
+def quorum_size(weights: np.ndarray,
+                order: Optional[Sequence[int]] = None) -> int:
+    """Smallest k such that the first k replicas of ``order`` (default: in
+    index order) carry weight strictly over ``consensus_threshold(weights)``;
+    0 if they never do. ``order`` may leave out replicas that do not vote.
+    The prefix sum runs in float64 from the first replica of ``order`` on,
+    as a replica accumulates its votes."""
+    w = np.asarray(weights)
+    csum = np.cumsum(w if order is None else w[np.asarray(order, np.int64)],
+                     dtype=np.float64)
+    hit = crosses(csum, float(consensus_threshold(w)))
+    return int(np.argmax(hit)) + 1 if hit.size and hit[-1] else 0
+
+
+def count_majority(n: int) -> int:
+    """Votes a count quorum of ``n`` needs: the rule over unit weights,
+    ``n // 2 + 1``."""
+    return n // 2 + 1
 
 
 class QuorumResult(NamedTuple):
@@ -49,10 +85,8 @@ def quorum_commit(arrivals: jax.Array, weights: jax.Array,
     Args:
       arrivals: (ops, n) vote arrival times; ``inf`` = no vote.
       weights:  (ops, n) per-replica vote weight for this op's object.
-      threshold: (ops,) consensus threshold; defaults to ``sum(weights)/2``
-        (paper §3.1). NOTE: the default sums *all* weights, including
-        non-voters — the threshold is a property of the object, not of who
-        happens to answer.
+      threshold: (ops,) consensus threshold; defaults to
+        :func:`consensus_threshold` of ``weights``, non-voters included.
 
     Returns a :class:`QuorumResult`.
     """
@@ -60,7 +94,7 @@ def quorum_commit(arrivals: jax.Array, weights: jax.Array,
         arrivals = arrivals[None]
         weights = weights[None]
     if threshold is None:
-        threshold = jnp.sum(weights, axis=-1) / 2.0
+        threshold = consensus_threshold(weights)
 
     order = jnp.argsort(arrivals, axis=-1)               # earliest vote first
     t_sorted = jnp.take_along_axis(arrivals, order, axis=-1)
@@ -69,32 +103,23 @@ def quorum_commit(arrivals: jax.Array, weights: jax.Array,
     w_sorted = jnp.where(jnp.isfinite(t_sorted), w_sorted, 0.0)
     csum = jnp.cumsum(w_sorted, axis=-1)
 
-    # STRICT crossing: two disjoint sets can each reach exactly sum/2 when
-    # weights are uniform and n even — Theorem 1's intersection argument
-    # needs accumulated weight to strictly exceed half the total.
-    crossed = csum > threshold[..., None]                # (ops, n) monotone
+    crossed = crosses(csum, threshold[..., None])        # (ops, n) monotone
     committed = jnp.any(crossed & jnp.isfinite(t_sorted), axis=-1)
     # first crossing index; argmax returns 0 when nothing crossed, so mask
     k = jnp.argmax(crossed, axis=-1)
     commit_time = jnp.where(
         committed, jnp.take_along_axis(t_sorted, k[..., None], axis=-1)[..., 0],
         INF)
-    quorum_size = jnp.where(committed, k + 1, 0).astype(jnp.int32)
+    size = jnp.where(committed, k + 1, 0).astype(jnp.int32)
     weight_sum = jnp.where(
         committed, jnp.take_along_axis(csum, k[..., None], axis=-1)[..., 0],
         0.0)
 
     # membership: replicas whose sorted position <= k and which actually voted
-    n = arrivals.shape[-1]
     pos_in_sorted = jnp.argsort(order, axis=-1)          # position of replica i
     members = (pos_in_sorted <= k[..., None]) & committed[..., None]
     members = members & jnp.isfinite(arrivals)
-    del n
-    return QuorumResult(committed, commit_time, quorum_size, weight_sum,
-                        members)
-
-
-quorum_commit_jit = jax.jit(quorum_commit)
+    return QuorumResult(committed, commit_time, size, weight_sum, members)
 
 
 def quorums_intersect(members_a: jax.Array, members_b: jax.Array) -> jax.Array:

@@ -56,6 +56,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Tuple
 
+from repro.core import quorum as Q
+
 
 @dataclasses.dataclass(frozen=True)
 class ReassignConfig:
@@ -241,7 +243,9 @@ class ReassignManager:
             for r in peers:
                 if now - last_hb[r] <= hb_to:
                     fresh_w += float(vw[r])
-            table.flat = fresh_w <= table.half_sum
+            # the geometric threshold, not current_threshold(): the test
+            # decides whether the flat fallback applies at all
+            table.flat = not Q.crosses(fresh_w, table.half_sum)
         if rep.recovering or rep._isolated:
             return
         leader = rep.current_leader(now)
@@ -293,7 +297,7 @@ class ReassignManager:
                 continue
             for r in sus:
                 votes[r] = votes.get(r, 0) + 1
-        need = cfg.min_reports or (n // 2 + 1)
+        need = cfg.min_reports or Q.count_majority(n)
         sus = sorted(r for r, v in votes.items() if v >= need and r < n)
         target = ([r for r in range(n) if r not in sus] + sus) if sus \
             else self._identity

@@ -1,72 +1,91 @@
-"""Geometric weight assignment and weighted-quorum invariants (paper §3.1–3.2).
+"""The weights (paper §3.1–3.2), in one place.
 
-Everything here is pure and vectorized: weight vectors are computed for
-batches of objects at once (shape ``(num_objects, n_replicas)``), because the
-Object Manager re-derives weights continuously from latency statistics and a
-production deployment tracks millions of objects.
+Everything that turns a deployment and its latency observations into vote
+weights lives here, each computed once:
 
-Notation (paper §3.1):
-  * object weight vector  W^O = [w_1^O .. w_n^O]
-  * consensus threshold   T^O = sum(W^O) / 2
-  * quorum                any S with sum_{i in S} w_i^O >= T^O
+  * :func:`clamp_faults` — the fault threshold a group of n can run,
+  * :func:`solve_steepness` — the steepness R for (n, t),
+  * :func:`geometric_weights_np` — the geometric base, one arithmetic:
+    replicas sorted by decreasing efficiency get ``w_i = R^(n-1-i)`` for
+    rank i in [0, n) (paper §3.2, eq. 1),
+  * :func:`latency_ranks` — the rank of each replica by latency,
+  * :class:`ObjectWeightTable` — a replica's per-object and node-level
+    weights (numpy, event-loop fast).
 
-Geometric assignment (paper §3.2, eq. 1): replicas sorted by decreasing
-efficiency get ``w_i = R^(n-1-i)`` for rank i in [0, n).
+The jax forms (:func:`geometric_weights`, :class:`WeightTracker`,
+:func:`node_weights_from_latency`) are built on the same base and rank
+rule, batched over objects (shape ``(num_objects, n_replicas)``), and
+return the values the numpy forms return. The quorum rule they feed
+(``T^O = sum(W^O)/2``, crossed strictly) lives in :mod:`repro.core.quorum`.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.core.quorum import consensus_threshold, crosses
 
 # Steepness bounds from the paper (§3.2): R in [1.0, 2.0].
 R_MIN = 1.0
 R_MAX = 2.0
 
 
-def geometric_weights(n: int, r: float, dtype=jnp.float32) -> jax.Array:
-    """Weights for ``n`` replicas ordered fastest-first: w_i = r^(n-1-i).
+def max_faults(n: int) -> int:
+    """The most faults a group of ``n`` can tolerate: ``floor((n-1)/2)``."""
+    return (n - 1) // 2
 
-    Returns a descending weight vector; ``w[-1] == 1.0`` always (rank n-1
-    gets r^0), matching Table 1/2 of the paper.
-    """
-    if n < 1:
-        raise ValueError(f"need at least one replica, got n={n}")
-    if not (R_MIN <= r <= R_MAX):
-        raise ValueError(f"steepness r={r} outside paper range [{R_MIN}, {R_MAX}]")
-    exponents = jnp.arange(n - 1, -1, -1, dtype=dtype)
-    if (n - 1) * np.log(max(r, 1.0 + 1e-12)) > 60.0:
-        # large fleets: r^(n-1) overflows float32. Quorum math is scale-
-        # invariant (threshold = sum/2), so normalize to w_max = 1
-        # (descending from 1 instead of descending to 1).
-        exponents = exponents - (n - 1)
-    return jnp.power(jnp.asarray(r, dtype=dtype), exponents)
+
+def clamp_faults(n: int, t: int) -> int:
+    """A requested fault threshold clamped to ``1..max_faults(n)``, the
+    range :func:`solve_steepness` accepts."""
+    return max(1, min(t, max_faults(n)))
 
 
 def geometric_weights_np(n: int, r: float,
                          dtype=np.float32) -> np.ndarray:
-    """Pure-numpy twin of :func:`geometric_weights` for the event-driven
-    simulator's replica constructors: the discrete-event path must stay
-    free of jax *execution*, because its worker and served-replica
-    processes must never start a jax backend (an accelerator belongs to
-    the one process that launched them)."""
+    """The geometric base: weights for ``n`` replicas ordered fastest-first,
+    ``w_i = r^(n-1-i)``, descending to ``w[-1] == 1.0`` (Table 1/2 of the
+    paper). Computed in float64, then cast to ``dtype``.
+
+    numpy, not jax: the event-driven simulator's worker processes and the
+    served replicas build their weights from it and must never start a jax
+    backend (an accelerator belongs to the one process that launched
+    them)."""
     if n < 1:
         raise ValueError(f"need at least one replica, got n={n}")
     if not (R_MIN <= r <= R_MAX):
         raise ValueError(f"steepness r={r} outside paper range [{R_MIN}, {R_MAX}]")
     exponents = np.arange(n - 1, -1, -1, dtype=np.float64)
     if (n - 1) * np.log(max(r, 1.0 + 1e-12)) > 60.0:
+        # large fleets: r^(n-1) overflows float32. Quorum math is scale-
+        # invariant (threshold = sum/2), so normalize to w_max = 1
+        # (descending from 1 instead of descending to 1).
         exponents = exponents - (n - 1)
     return np.power(np.float64(r), exponents).astype(dtype)
 
 
-def consensus_threshold(weights: jax.Array) -> jax.Array:
-    """T = sum(w)/2 over the last axis (paper §3.1)."""
-    return jnp.sum(weights, axis=-1) / 2.0
+def geometric_weights(n: int, r: float, dtype=jnp.float32) -> jax.Array:
+    """:func:`geometric_weights_np` as a jnp array: the same values."""
+    return jnp.asarray(geometric_weights_np(n, r, dtype))
+
+
+def latency_ranks(latency, out=None):
+    """Rank of each replica by latency along the last axis, 0 = fastest;
+    equal latencies rank in replica order (a stable sort). A jnp array
+    (any leading axes) gives jnp ranks; a numpy vector or list gives
+    numpy ranks, written into ``out`` when it is given."""
+    if isinstance(latency, jax.Array):
+        order = jnp.argsort(latency, axis=-1, stable=True)
+        return jnp.argsort(order, axis=-1)
+    order = np.argsort(latency, kind="stable")
+    ranks = np.empty_like(order) if out is None else out
+    ranks[order] = np.arange(order.size)
+    return ranks
 
 
 def cabinet_size(weights_desc: jax.Array) -> jax.Array:
@@ -77,10 +96,7 @@ def cabinet_size(weights_desc: jax.Array) -> jax.Array:
     Vectorized over leading axes.
     """
     csum = jnp.cumsum(weights_desc, axis=-1)
-    thresh = consensus_threshold(weights_desc)[..., None]
-    # first index where cumulative weight STRICTLY exceeds T (see
-    # repro.core.quorum: >= admits disjoint quorums at exactly sum/2)
-    meets = csum > thresh
+    meets = crosses(csum, consensus_threshold(weights_desc)[..., None])
     return jnp.argmax(meets, axis=-1) + 1
 
 
@@ -92,7 +108,7 @@ def check_invariant_progress(weights: jax.Array, t: int) -> jax.Array:
     """
     w_sorted = jnp.sort(weights, axis=-1)[..., ::-1]
     top = jnp.sum(w_sorted[..., : t + 1], axis=-1)
-    return top > consensus_threshold(weights)
+    return crosses(top, consensus_threshold(weights))
 
 
 def check_invariant_safety(weights: jax.Array, t: int) -> jax.Array:
@@ -105,7 +121,7 @@ def check_invariant_safety(weights: jax.Array, t: int) -> jax.Array:
         return jnp.ones(weights.shape[:-1], dtype=bool)
     w_sorted = jnp.sort(weights, axis=-1)[..., ::-1]
     top_t = jnp.sum(w_sorted[..., :t], axis=-1)
-    return top_t <= consensus_threshold(weights)
+    return ~crosses(top_t, consensus_threshold(weights))
 
 
 def max_safe_t(weights: jax.Array) -> jax.Array:
@@ -132,7 +148,7 @@ def solve_steepness(n: int, t: int, *, tol: float = 1e-9) -> float:
     t=3 -> ~1.19..1.25, t=4 -> ~1.08..1.10) come from this feasibility
     region; we return the supremum minus a safety margin.
     """
-    if not (1 <= t <= (n - 1) // 2):
+    if not (1 <= t <= max_faults(n)):
         raise ValueError(f"t={t} outside 1..floor((n-1)/2) for n={n}")
 
     def top_t_fraction(r: float) -> float:
@@ -162,6 +178,116 @@ def solve_steepness(n: int, t: int, *, tol: float = 1e-9) -> float:
 # ---------------------------------------------------------------------------
 # Dynamic weight assignment (paper §3.1 "Dynamic weight assignment")
 # ---------------------------------------------------------------------------
+
+
+class ObjectWeightTable:
+    """Per-object latency EMA -> geometric weights (numpy, event-loop fast).
+
+    The returned weight vectors are permutations of ``base`` and treated as
+    read-only by callers, so the node-level fallback (the common case: a
+    first-touch object has no EMA of its own) is cached and recomputed only
+    when the node EMA changes (``node_version`` is bumped by
+    ``BaseReplica.observe_node``).
+    """
+
+    def __init__(self, n: int, r: float, node_ema: np.ndarray,
+                 decay: float = 0.85):
+        self.n = n
+        self.base = geometric_weights_np(n, r)             # descending by rank
+        self.half_sum = float(consensus_threshold(self.base))
+        self.decay = decay
+        # per-object EMAs are plain float lists: element updates in
+        # ``observe`` are ~5x cheaper than numpy scalar writes, and the
+        # argsort in ``_weights_of`` converts on the (much rarer) read
+        self.ema: Dict[int, list] = {}
+        self.node_ema = node_ema  # shared fallback: node-level latency EMA
+        self.node_version = 0
+        self._nw_version = -1
+        self._nw: np.ndarray | None = None
+        self._ranks = np.empty(n, dtype=np.int64)   # scratch
+        self._arange = np.arange(n)
+        # installed weight view (repro.core.reassign): while active, the
+        # epoch-stamped ranking overrides BOTH the per-object EMAs and
+        # the node-level ranking — the view is the shared truth all
+        # replicas quorum under, private telemetry resumes on restore.
+        self.rank_of: np.ndarray | None = None
+        # flat fallback (graceful degradation): when the view-weighted
+        # heartbeat-fresh set cannot strictly cross half_sum, quorums
+        # degrade to count-majorities (weights 1, threshold n/2)
+        self.flat = False
+        self._flat_w = np.ones(n, dtype=np.float64)
+        self._flat_threshold = float(consensus_threshold(self._flat_w))
+
+    def observe(self, obj: int, replica: int, latency: float) -> None:
+        e = self.ema.get(obj)
+        if e is None:
+            e = self.ema[obj] = self.node_ema.tolist()
+        e[replica] = self.decay * e[replica] + (1 - self.decay) * latency
+
+    def _weights_of(self, e: np.ndarray) -> np.ndarray:
+        return self.base[latency_ranks(e, self._ranks)]
+
+    def view_weights(self) -> np.ndarray:
+        """Node weights under the current view, ignoring the flat
+        fallback (the fallback's own trigger test needs these)."""
+        if self._nw_version != self.node_version:
+            ro = self.rank_of
+            self._nw = self.base[ro] if ro is not None \
+                else self._weights_of(self.node_ema)
+            self._nw_version = self.node_version
+        return self._nw
+
+    def node_weights(self) -> np.ndarray:
+        """Node-level weights, cached per node-EMA/view version."""
+        if self.flat:
+            return self._flat_w
+        return self.view_weights()
+
+    def weights_for(self, obj: int) -> np.ndarray:
+        if self.flat:
+            return self._flat_w
+        if self.rank_of is not None:
+            return self.view_weights()
+        e = self.ema.get(obj)
+        if e is None:
+            return self.view_weights()
+        return self._weights_of(e)
+
+    def current_threshold(self) -> float:
+        return self._flat_threshold if self.flat else self.half_sum
+
+    def threshold_for(self, obj: int) -> float:
+        return self.current_threshold()            # T^O = sum(W^O)/2
+
+    def shared_weights(self) -> np.ndarray:
+        """Weights under the SHARED election ranking: the epoch-stamped
+        installed view when present (``view_weights`` is then exactly
+        ``base[rank_of]``, cached), else the static deployment ranking
+        (replica id == rank). Unlike ``node_weights`` this never consults
+        the private latency EMA: every node zeroes its own EMA entry and
+        so ranks ITSELF top-weight in its private view, which would let
+        two partition sides both believe they hold the weighted
+        majority. The leadership lease and the isolation detector must
+        evaluate one vector that is identical at every replica."""
+        if self.flat:
+            return self._flat_w
+        if self.rank_of is not None:
+            return self.view_weights()
+        return self.base
+
+    def set_rank_override(self, ranking) -> None:
+        """Install (or with ``None`` clear) an epoch-stamped ranking:
+        ``ranking[0]`` gets the top geometric weight. Per-object EMAs
+        are dropped either way — telemetry gathered under the previous
+        weight regime must not leak into the new one."""
+        if ranking is None:
+            self.rank_of = None
+        else:
+            ro = np.empty(self.n, dtype=np.int64)
+            ro[np.asarray(ranking, dtype=np.int64)] = self._arange
+            self.rank_of = ro
+        self.ema.clear()
+        self.node_version += 1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -207,16 +333,12 @@ class WeightTracker:
 
         Fastest (lowest EMA) replica per object gets the highest weight.
         """
-        num_objects, n = self.latency_ema.shape
-        order = jnp.argsort(self.latency_ema, axis=-1)  # fastest first
-        ranks = jnp.argsort(order, axis=-1)             # rank of each replica
-        base = geometric_weights(n, r)                  # descending by rank
-        return base[ranks]
+        n = self.latency_ema.shape[-1]
+        return geometric_weights(n, r)[self.ranks()]
 
     def ranks(self) -> jax.Array:
         """Rank (0 = fastest) of each replica per object."""
-        order = jnp.argsort(self.latency_ema, axis=-1)
-        return jnp.argsort(order, axis=-1)
+        return latency_ranks(self.latency_ema)
 
 
 def node_weights_from_latency(latency_ema: jax.Array, r: float) -> jax.Array:
@@ -224,10 +346,8 @@ def node_weights_from_latency(latency_ema: jax.Array, r: float) -> jax.Array:
 
     ``latency_ema``: (n,) cross-object replica latency EMA.
     """
-    order = jnp.argsort(latency_ema)
-    ranks = jnp.argsort(order)
-    base = geometric_weights(latency_ema.shape[-1], r)
-    return base[ranks]
+    return geometric_weights(latency_ema.shape[-1], r)[
+        latency_ranks(latency_ema)]
 
 
 def paper_table1() -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -238,8 +358,8 @@ def paper_table1() -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     ObjD (t=3, R=1.10).
     """
     rs = np.array([1.40, 1.38, 1.25, 1.10])
-    w = np.stack([np.asarray(geometric_weights(7, float(r))) for r in rs])
-    return rs, w, w.sum(axis=-1) / 2.0
+    w = np.stack([geometric_weights_np(7, float(r)) for r in rs])
+    return rs, w, consensus_threshold(w)
 
 
 def paper_table2() -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -248,5 +368,5 @@ def paper_table2() -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     Rows: t=1 (R=1.40), t=2 (R=1.38), t=3 (R=1.19), t=4 (R=1.08).
     """
     rs = np.array([1.40, 1.38, 1.19, 1.08])
-    w = np.stack([np.asarray(geometric_weights(7, float(r))) for r in rs])
-    return rs, w, w.sum(axis=-1) / 2.0
+    w = np.stack([geometric_weights_np(7, float(r)) for r in rs])
+    return rs, w, consensus_threshold(w)

@@ -1,10 +1,12 @@
-"""Pallas TPU kernels for the framework's compute hot spots.
+"""Pallas TPU kernel for the protocol's compute hot spot: the batched
+weighted-quorum commit scan. It imports nothing of the LM stack (whose
+kernels live in ``repro.models.kernels``).
 
-Each kernel ships three layers (see tests/test_kernels.py for the
-interpret-mode allclose sweeps):
-  * <name>.py — pl.pallas_call with explicit BlockSpec VMEM tiling
-  * ops.py    — jit'd wrappers (TPU -> kernel, elsewhere -> oracle)
-  * ref.py    — pure-jnp oracles (the exact code the models run on CPU)
+The kernel ships three layers (see tests/test_kernels.py for the
+interpret-mode sweeps):
+  * quorum_commit.py — pl.pallas_call with explicit BlockSpec VMEM tiling
+  * ops.py    — jit'd wrapper (TPU -> kernel, elsewhere -> oracle)
+  * ref.py    — the pure-jnp oracle (repro.core.quorum)
 """
 
 from repro.kernels import ops, ref

@@ -1,29 +1,14 @@
-"""Pure-jnp oracles for every Pallas kernel (asserted allclose in tests).
-
-These re-export the canonical implementations from the library so the
-kernels validate against the exact code the models run on CPU.
-"""
+"""Pure-jnp oracle for the quorum kernel (asserted equal in tests): the
+library's own :func:`repro.core.quorum.quorum_commit`, so the kernel
+validates against the rule the protocol runs."""
 
 from __future__ import annotations
 
 import jax.numpy as jnp
 
 from repro.core.quorum import quorum_commit as _quorum_commit
-from repro.models.layers import attend_chunked, attend_full
-from repro.models.mamba2 import ssd_chunked
 
 
 def quorum_commit_ref(arrivals, weights):
     res = _quorum_commit(jnp.asarray(arrivals), jnp.asarray(weights))
     return (res.commit_time, res.quorum_size, res.committed, res.weight_sum)
-
-
-def flash_attention_ref(q, k, v, *, causal: bool = True):
-    if q.shape[1] >= 512:
-        return attend_chunked(q, k, v, causal=causal)
-    return attend_full(q, k, v, causal=causal)
-
-
-def ssd_ref(x, dt, A, Bm, Cm, D, chunk, initial_state=None):
-    return ssd_chunked(x, dt, A, Bm, Cm, D, chunk,
-                       initial_state=initial_state)

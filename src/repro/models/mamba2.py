@@ -87,7 +87,7 @@ def param_specs(cfg, rules):
 
 
 # ---------------------------------------------------------------------------
-# SSD core (chunked scan) — also the jnp oracle for kernels/ssd_scan
+# SSD core (chunked scan) — also the jnp oracle for models/kernels/ssd_scan
 # ---------------------------------------------------------------------------
 
 def _split_proj(params, cfg, u):

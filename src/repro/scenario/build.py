@@ -18,6 +18,7 @@ from __future__ import annotations
 
 from typing import Union
 
+from repro.core import weights as W
 from repro.core.runner import RunArtifacts, client_target_fn
 from repro.core.simulator import Client, Simulation, collect_metrics
 from repro.faults import compile_schedule
@@ -120,7 +121,7 @@ def _run_flat(sc: Scenario) -> RunArtifacts:
         from repro.obs.spans import Tracer
         sim.tracer = Tracer(sample_every=sc.obs.sample_every)
     cls = protocol_class(sc.protocol)
-    t = max(1, min(sc.t_fail, (sc.n_replicas - 1) // 2))
+    t = W.clamp_faults(sc.n_replicas, sc.t_fail)
     leases = _lease_cfg(sc)
     reassign = _reassign_cfg(sc)
     coding = _coding_cfg(sc)

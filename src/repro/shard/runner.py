@@ -37,6 +37,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.core import weights as W
 from repro.core.simulator import CostModel, Simulation, Workload
 from repro.scenario.registry import protocol_class
 from repro.shard.gate import GroupGate, make_sharded_replica
@@ -281,7 +282,7 @@ def build_group(sim, cfg: ShardedRunConfig, g: int,
     partitioned EventEngine) and start their heartbeats."""
     npg = cfg.n_replicas_per_group
     cls = make_sharded_replica(protocol_class(cfg.protocol))
-    t = max(1, min(cfg.t_fail, (npg - 1) // 2))
+    t = W.clamp_faults(npg, cfg.t_fail)
     view = GroupView(sim, g, npg)
     grp = [cls(i, view, gate=gate, t_fail=t,
                group_cap=max(cfg.batch_size, 1),

@@ -60,6 +60,7 @@ import sys
 import time
 from pathlib import Path
 
+from repro.core import weights as W
 from repro.core.rsm import apply_digest
 from repro.scenario.registry import protocol_class
 from repro.transport.codec import (decode_body, decode_hello, read_frame,
@@ -123,7 +124,7 @@ def served_replica(protocol: str, node_id: int, ctx: NetContext,
     """The replica a served process runs: its slow-path instances carry
     every batch queued at the leader, bounded by the frame limit
     (``core/slowpath.py``) and not by a batch count."""
-    t = max(1, min(t_fail, (ctx.n - 1) // 2))
+    t = W.clamp_faults(ctx.n, t_fail)
     replica = protocol_class(protocol)(node_id, ctx, t_fail=t,
                                        group_cap=None)
     replica.frame_fits = slow_instance_fits

@@ -1,6 +1,10 @@
 """Pallas kernels vs pure-jnp oracles in interpret mode (CPU), with
 hypothesis sweeps over shapes/dtypes."""
 
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,9 +12,10 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention
 from repro.kernels.quorum_commit import quorum_commit_pallas
-from repro.kernels.ssd_scan import ssd_chunked_pallas
+from repro.models.kernels import ref as lm_ref
+from repro.models.kernels.flash_attention import flash_attention
+from repro.models.kernels.ssd_scan import ssd_chunked_pallas
 
 
 # ---------------------------------------------------------------------------
@@ -71,6 +76,19 @@ def test_quorum_commit_geometric_weights_top2():
     np.testing.assert_allclose(np.asarray(ct), 2.0)
 
 
+def test_quorum_kernel_ops_load_no_model_code():
+    """The protocol's one device call must not drag in the LM stack."""
+    code = ("import sys, repro.kernels.ops; "
+            "print(sorted(m for m in sys.modules "
+            "if m.startswith('repro.models')))")
+    src = os.path.dirname(os.path.dirname(os.path.dirname(ref.__file__)))
+    out = subprocess.run([sys.executable, "-c", code], check=True,
+                         capture_output=True, text=True, timeout=300,
+                         env={**os.environ, "JAX_PLATFORMS": "cpu",
+                              "PYTHONPATH": src})
+    assert out.stdout.strip() == "[]", out.stdout
+
+
 # ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------
@@ -89,7 +107,7 @@ def test_flash_attention_matches_ref(dtype, B, S, H, KV, hd, bq, bk):
     v = jax.random.normal(kv_, (B, S, KV, hd), dtype)
     out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
                           interpret=True)
-    want = ref.flash_attention_ref(q, k, v, causal=True)
+    want = lm_ref.flash_attention_ref(q, k, v, causal=True)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32),
@@ -102,7 +120,7 @@ def test_flash_attention_non_causal():
     k = jax.random.normal(jax.random.fold_in(rng, 1), (1, 256, 2, 64))
     v = jax.random.normal(jax.random.fold_in(rng, 2), (1, 256, 2, 64))
     out = flash_attention(q, k, v, causal=False, interpret=True)
-    want = ref.flash_attention_ref(q, k, v, causal=False)
+    want = lm_ref.flash_attention_ref(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
 
@@ -122,7 +140,7 @@ def test_flash_attention_shape_sweep(data):
     v = jax.random.normal(jax.random.fold_in(rng, 2), (1, S, KV, hd))
     out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=128,
                           interpret=True)
-    want = ref.flash_attention_ref(q, k, v, causal=True)
+    want = lm_ref.flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=3e-5, rtol=3e-5)
 
@@ -146,7 +164,7 @@ def test_ssd_matches_ref(B, S, nh, hp, N, Q):
     Cm = jax.random.normal(ks[4], (B, S, N), jnp.float32)
     D = jnp.ones((nh,))
     y, st_ = ssd_chunked_pallas(x, dt, A, Bm, Cm, D, Q, interpret=True)
-    ry, rst = ref.ssd_ref(x, dt, A, Bm, Cm, D, Q)
+    ry, rst = lm_ref.ssd_ref(x, dt, A, Bm, Cm, D, Q)
     np.testing.assert_allclose(np.asarray(y), np.asarray(ry),
                                atol=2e-3, rtol=2e-3)
     np.testing.assert_allclose(np.asarray(st_), np.asarray(rst),
@@ -193,10 +211,10 @@ def test_ssd_initial_state_threading():
     Bm = jax.random.normal(ks[3], (B, S, N), jnp.float32)
     Cm = jax.random.normal(ks[4], (B, S, N), jnp.float32)
     D = jnp.zeros((nh,))
-    y_full, s_full = ref.ssd_ref(x, dt, A, Bm, Cm, D, Q)
+    y_full, s_full = lm_ref.ssd_ref(x, dt, A, Bm, Cm, D, Q)
     h = S // 2
-    y1, s1 = ref.ssd_ref(x[:, :h], dt[:, :h], A, Bm[:, :h], Cm[:, :h], D, Q)
-    y2, s2 = ref.ssd_ref(x[:, h:], dt[:, h:], A, Bm[:, h:], Cm[:, h:], D, Q,
+    y1, s1 = lm_ref.ssd_ref(x[:, :h], dt[:, :h], A, Bm[:, :h], Cm[:, :h], D, Q)
+    y2, s2 = lm_ref.ssd_ref(x[:, h:], dt[:, h:], A, Bm[:, h:], Cm[:, h:], D, Q,
                          initial_state=s1)
     np.testing.assert_allclose(np.asarray(jnp.concatenate([y1, y2], 1)),
                                np.asarray(y_full), atol=2e-3, rtol=2e-3)

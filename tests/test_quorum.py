@@ -2,8 +2,10 @@
 
 import numpy as np
 import jax.numpy as jnp
+import pytest
 from _hypothesis_compat import given, settings, st
 
+from repro.core import quorum as Q
 from repro.core import weights as W
 from repro.core.quorum import quorum_commit, quorums_intersect
 
@@ -81,3 +83,94 @@ def test_commit_with_top_heavy_quorum():
     assert bool(res.committed[0])
     assert int(res.quorum_size[0]) == 2
     assert float(res.commit_time[0]) == 1.0
+
+
+# ---------------------------------------------------------------------------
+# One rule, every form: the host prefix rule, the jnp oracle, the Pallas
+# kernel, cabinet_size, the lease k_max, GradQuorum and CheckpointConsensus
+# agree on the quorum size of a geometric base under an arrival order.
+# ---------------------------------------------------------------------------
+
+RULE_CASES = [
+    # uniform weights, even n: n/2 votes reach sum/2 exactly and must not
+    # commit, so the quorum is the count majority
+    pytest.param(4, 1.0, None, id="uniform-4"),
+    pytest.param(6, 1.0, None, id="uniform-6"),
+    pytest.param(8, 1.0, None, id="uniform-8"),
+    # paper Table 1: ObjA..ObjD
+    pytest.param(7, 1.40, None, id="table1-ObjA"),
+    pytest.param(7, 1.38, None, id="table1-ObjB"),
+    pytest.param(7, 1.25, None, id="table1-ObjC"),
+    pytest.param(7, 1.10, None, id="table1-ObjD"),
+    # a 64-replica geometric fleet
+    pytest.param(64, W.solve_steepness(64, 2), None, id="fleet64-t2"),
+    pytest.param(64, W.solve_steepness(64, 10), None, id="fleet64-t10"),
+    # random arrival orders
+    pytest.param(6, 1.0, 5, id="random-uniform-6"),
+    pytest.param(7, 1.40, 1, id="random-table1-ObjA"),
+    pytest.param(7, 1.10, 2, id="random-table1-ObjD"),
+    pytest.param(9, W.solve_steepness(9, 2), 3, id="random-9-t2"),
+    pytest.param(64, W.solve_steepness(64, 2), 4, id="random-fleet64-t2"),
+]
+
+
+@pytest.mark.parametrize("n,r,seed", RULE_CASES)
+def test_every_form_of_the_rule_agrees(n, r, seed):
+    from repro.coord import CheckpointConsensus, GradQuorum
+    from repro.core.cabinet import CabinetReplica
+    from repro.core.leases import LeaseConfig
+    from repro.core.simulator import Simulation
+    from repro.kernels.quorum_commit import quorum_commit_pallas
+
+    base = W.geometric_weights_np(n, r)
+    order = (np.arange(n) if seed is None
+             else np.random.default_rng(seed).permutation(n))
+    arrivals = np.empty(n, np.float32)
+    arrivals[order] = np.arange(1, n + 1)
+    want = Q.quorum_size(base, order)
+    assert 1 <= want <= n
+    if r == 1.0:
+        assert want == Q.count_majority(n)
+
+    res = quorum_commit(jnp.asarray(arrivals), jnp.asarray(base))
+    assert bool(res.committed[0]) and int(res.quorum_size[0]) == want
+    _, qs, cm, _ = quorum_commit_pallas(jnp.asarray(arrivals[None]),
+                                        jnp.asarray(base[None]),
+                                        interpret=True)
+    assert bool(cm[0]) and int(qs[0]) == want
+    assert int(W.cabinet_size(jnp.asarray(base[order]))) == want
+
+    # equal latency EMAs rank the workers by id: worker i carries base[i]
+    gq = GradQuorum(n)
+    gq.state.steepness = r
+    assert int(gq.commit_mask(arrivals).sum()) == want
+
+    cc = CheckpointConsensus(n, steepness=r)
+    cc.propose(0, [])
+    acks = next(j + 1 for j, h in enumerate(order) if cc.ack(0, int(h)))
+    assert acks == want
+
+    # the lease's k_max: the most top-ranked replicas that cannot cross
+    rep = CabinetReplica(0, Simulation(n), t_fail=1, steepness=r,
+                         group_cap=1, leases=LeaseConfig())
+    k_max = n - 1 - rep.lease_mgr._ll_need
+    assert k_max + 1 == Q.quorum_size(base)
+
+
+@pytest.mark.parametrize("n,r", [(5, 1.4), (7, 1.10), (9, 1.9),
+                                 (64, 1.5), (200, 2.0)])
+def test_jax_weight_forms_equal_the_numpy_forms(n, r):
+    np.testing.assert_array_equal(np.asarray(W.geometric_weights(n, r)),
+                                  W.geometric_weights_np(n, r))
+    rng = np.random.default_rng(n)
+    emas = rng.permutation(3 * n).reshape(3, n) * 1e-3 + 1e-3
+    table = W.ObjectWeightTable(n, r, emas[0].copy())
+    for obj in (1, 2):
+        table.ema[obj] = emas[obj].tolist()
+    tracker = W.WeightTracker(latency_ema=jnp.asarray(emas, jnp.float32))
+    got = np.asarray(tracker.weights(r))
+    for obj in (1, 2):
+        np.testing.assert_array_equal(got[obj], table.weights_for(obj))
+    np.testing.assert_array_equal(
+        np.asarray(W.node_weights_from_latency(jnp.asarray(emas[0]), r)),
+        table.node_weights())
