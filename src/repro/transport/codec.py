@@ -24,7 +24,9 @@ A broadcast's frames differ only in the destination id, so
 copies of the envelope; its frames are byte for byte ``encode_msg``'s.
 
 The framing and the codec are deliberately independent of asyncio: the
-unit tests round-trip encoded messages without opening a socket.
+unit tests round-trip encoded messages without opening a socket, and
+the transport's connections cut their read buffers with the same
+:func:`split_frames`.
 """
 
 from __future__ import annotations
@@ -194,28 +196,30 @@ def decode_hello(body: bytes) -> int:
     return json.loads(body)["hello"]
 
 
-async def read_frame(reader) -> bytes:
-    """Read one frame body from an asyncio StreamReader (raises
-    ``asyncio.IncompleteReadError`` on EOF, ``ValueError`` on a corrupt
-    length prefix)."""
-    head = await reader.readexactly(HEADER.size)
-    (length,) = HEADER.unpack(head)
-    if length > MAX_FRAME:
-        raise ValueError(f"frame length {length} exceeds MAX_FRAME")
-    return await reader.readexactly(length)
+def split_frames(buf) -> Tuple[list, bytes]:
+    """Split a byte buffer into its complete frame bodies, in order, and
+    the unconsumed tail (a partial frame, or nothing). Raises
+    ``ValueError`` on a length prefix over ``MAX_FRAME``, as soon as the
+    header is in: a corrupt length need not wait for its body.
 
-
-def split_frames(buf: bytes) -> Tuple[list, bytes]:
-    """Codec-level helper for non-asyncio consumers/tests: split a byte
-    buffer into complete frame bodies + the unconsumed tail."""
+    A ``bytearray`` is consumed in place, as a socket's read buffer is:
+    the complete frames are cut from its front, and the tail returned is
+    the bytearray itself, not a copy of it. Bodies are ``bytes`` either
+    way."""
     out = []
     off = 0
-    while len(buf) - off >= HEADER.size:
-        (length,) = HEADER.unpack_from(buf, off)
-        if length > MAX_FRAME:
-            raise ValueError(f"frame length {length} exceeds MAX_FRAME")
-        if len(buf) - off - HEADER.size < length:
-            break
-        out.append(buf[off + HEADER.size: off + HEADER.size + length])
-        off += HEADER.size + length
+    size = len(buf)
+    with memoryview(buf) as view:
+        while size - off >= HEADER.size:
+            (length,) = HEADER.unpack_from(buf, off)
+            if length > MAX_FRAME:
+                raise ValueError(f"frame length {length} exceeds MAX_FRAME")
+            end = off + HEADER.size + length
+            if end > size:
+                break
+            out.append(bytes(view[off + HEADER.size:end]))
+            off = end
+    if isinstance(buf, bytearray):
+        del buf[:off]
+        return out, buf
     return out, buf[off:]

@@ -22,6 +22,16 @@ different substrate:
     have no bytes to share and do not offer it;
   * ``busy`` is a no-op — real CPU time charges itself.
 
+Frames cross between the event loop and the sockets in bulk. A send
+only queues its frame; the first one of a loop turn schedules its link's
+flush (``loop.call_soon``), which writes every frame queued by then with
+one ``transport.write``, so a busy loop pays one wake and one syscall for
+the many frames a long turn queues (:class:`PeerChannel`, and the reply
+route of :meth:`NetContext.register_client_writer`). Reads are
+``asyncio.Protocol`` callbacks (:class:`FrameProtocol`): each one cuts
+every complete frame out of the connection's buffer and hands them on in
+arrival order, with no task woken per frame.
+
 Clock-domain caveat: ``time.time`` can step (NTP); on a single host the
 histories this transport records are causally ordered by the sockets
 themselves, and the linearizability checker consumes invoke/response
@@ -31,10 +41,12 @@ would need a real clock-sync story; this transport targets loopback.
 Long-run memory contract (the soak assertions in tests/test_transport.py
 pin this): every per-peer table in this module is bounded —
 ``PeerChannel`` queues cap at ``max_queue`` frames (drop-oldest; the
-protocol's retransmit/retry layers re-drive), reconnect backoff is
-capped, and the ``read_results`` / ``commit_log`` reply-enrichment
-tables prune FIFO above a fixed cap (a retried op older than 64k
-credits would lose its path stamp in the reply — it keeps its ack).
+protocol's retransmit/retry layers re-drive), a client's reply route
+holds one loop turn's replies, a connection's read buffer at most one
+partial frame after each read, reconnect backoff is capped, and the
+``read_results`` / ``commit_log`` reply-enrichment tables prune FIFO
+above a fixed cap (a retried op older than 64k credits would lose its
+path stamp in the reply — it keeps its ack).
 Nothing here grows with the op count of the run except the tracer,
 which is explicitly sampled (the ``vote`` spans below are tracer
 events); a timed channel's queue stamps ride its bounded queue.
@@ -50,8 +62,8 @@ costs one ``None`` or flag test per frame):
     service and return leg (``repro.obs.critical_path``). It is a span
     of the tracer and shares its sampling.
   * ``PeerChannel(timed=True)`` stamps each frame as it is queued and
-    sums the time until ``writer.write`` into ``wait_s``: one float
-    per channel, whatever the op count.
+    sums the time until its flush's ``transport.write`` into ``wait_s``:
+    one float per channel, whatever the op count.
 """
 
 from __future__ import annotations
@@ -62,7 +74,8 @@ from collections import deque
 from typing import Callable, Dict, List, Optional, Sequence
 
 from repro.core.simulator import CostModel, Msg
-from repro.transport.codec import encode_fanout, encode_hello, encode_msg
+from repro.transport.codec import (encode_fanout, encode_hello, encode_msg,
+                                   split_frames)
 
 READ_RESULTS_CAP = 65536      # reply-enrichment table bound (FIFO prune)
 WRITE_BUF_LIMIT = 8 * 1024 * 1024   # per-client-socket backpressure bound
@@ -202,8 +215,9 @@ class NetContext:
 
     def deliver(self, msg: Msg) -> None:
         """Inbound frame -> protocol handler (called by the node
-        runner's connection reader). A traced replica records a ``vote``
-        span around its handling of a proposal (module docstring)."""
+        runner's inbound connections, in each read's frame order). A
+        traced replica records a ``vote`` span around its handling of a
+        proposal (module docstring)."""
         t_recv = self.now
         self._node.on_message(msg, t_recv)
         tr = self.tracer
@@ -247,23 +261,33 @@ class NetContext:
                       sender: Callable[[bytes], None]) -> None:
         self._senders[peer_id] = sender
 
-    def register_client_writer(self, client_id: int,
-                               writer: asyncio.StreamWriter) -> None:
-        """Replies to a client go back over the socket it dialed in on.
+    def register_client_writer(self, client_id: int, transport) -> None:
+        """Replies to a client go back over the socket it dialed in on
+        (its server-side ``transport``), flushed as a peer channel's
+        frames are: the replies of one loop turn leave in one write.
         Writes are bounded by the transport's write-buffer size: a stuck
         client drops replies (its retries re-drive) instead of growing
         the buffer without limit."""
+        loop = asyncio.get_running_loop()
+        pending: List[bytes] = []
+
+        def flush() -> None:
+            data = b"".join(pending)
+            pending.clear()
+            if not transport.is_closing():
+                transport.write(data)
 
         def send(data: bytes) -> None:
-            transport = writer.transport
-            if transport is None or transport.is_closing():
+            if transport.is_closing():
                 self._senders.pop(client_id, None)
                 self.dropped_no_route += 1
                 return
             if transport.get_write_buffer_size() > WRITE_BUF_LIMIT:
                 self.dropped_no_route += 1
                 return
-            writer.write(data)
+            if not pending:
+                loop.call_soon(flush)
+            pending.append(data)
 
         self._senders[client_id] = send
 
@@ -271,14 +295,81 @@ class NetContext:
         self._senders.pop(peer_id, None)
 
 
+class FrameProtocol(asyncio.Protocol):
+    """A connection's read side: every complete frame a read brings is cut
+    from the buffer (``codec.split_frames``) and handed to ``on_body``,
+    in arrival order, inside that one ``data_received``; a partial frame
+    waits in the buffer for the next read. A length over ``MAX_FRAME``,
+    or a ``ValueError`` from ``on_body`` (a body that does not decode),
+    aborts the connection. ``on_body`` may be replaced while a read's
+    frames are handed on: the next frame goes to the new one."""
+
+    def __init__(self, on_body: Callable[[bytes], None]):
+        self.on_body = on_body
+        self.transport = None
+        self._buf = bytearray()
+
+    def connection_made(self, transport) -> None:
+        self.transport = transport
+
+    def data_received(self, data: bytes) -> None:
+        buf = self._buf
+        buf += data
+        try:
+            bodies, _ = split_frames(buf)
+            for body in bodies:
+                self.on_body(body)
+        except ValueError:
+            self.transport.abort()
+
+
+def _ignore(body: bytes) -> None:
+    pass
+
+
+class _ChannelLink(FrameProtocol):
+    """The protocol of a :class:`PeerChannel`'s live connection: inbound
+    frames go to the channel's ``on_frame``; the transport's write
+    pauses and its loss go to the channel."""
+
+    def __init__(self, chan: "PeerChannel"):
+        super().__init__(chan.on_frame or _ignore)
+        self.chan = chan
+
+    def connection_made(self, transport) -> None:
+        super().connection_made(transport)
+        self.chan._up(transport)
+
+    def connection_lost(self, exc) -> None:
+        self.chan._down(self.transport)
+
+    def pause_writing(self) -> None:
+        self.chan._paused = True
+
+    def resume_writing(self) -> None:
+        self.chan._paused = False
+        self.chan._schedule()
+
+
 class PeerChannel:
-    """One outbound replica->replica connection: bounded queue, dial +
-    reconnect with capped exponential backoff, optional frame-reorder
-    mutation.
+    """One outbound connection to a replica: bounded queue, one flush per
+    loop turn, dial + reconnect with capped exponential backoff, optional
+    frame-reorder mutation.
+
+    ``send`` queues the frame (drop-oldest at ``max_queue``) and, if no
+    flush is due and the link is writable, schedules one with
+    ``loop.call_soon``. The flush writes every frame queued by then with
+    one ``transport.write`` (``writes`` counts them beside ``sent``).
+    While the transport has paused writing (its buffer over asyncio's
+    high-water mark) or the link is down, frames wait in the bounded
+    queue; resuming, or the next connection, flushes them.
 
     The address is re-resolved through ``addr_fn`` on every dial so a
     peer that restarts on a fresh port is picked up without any control
     plane (the node runner's port files are the discovery mechanism).
+    The dial runs at construction and again whenever the connection is
+    lost; frames already handed to a transport that dies are lost (drop
+    semantics: the protocol's retries re-drive them).
 
     ``reorder=True`` is the MUTATION TWIN for tests: every
     ``REORDER_EVERY``-th frame on this channel is held back and released
@@ -297,17 +388,22 @@ class PeerChannel:
     the checker may legally reorder those; rolling the store back past
     a dozen frames (several committed generations) makes the stale
     value's overwriters strictly real-time-before any witnessing read.
-    A transport with this bug must fail the linearizability checker —
-    that is what makes the checker-on-real-histories pipeline
-    trustworthy.
+    A frame held through ``REORDER_QUIET_S`` without a send is released
+    (liveness); releasing only after a quiet period, not the moment the
+    queue drains, is what lets the displacement straddle later frames on
+    a fast loopback link. A transport with this bug must fail the
+    linearizability checker — that is what makes the
+    checker-on-real-histories pipeline trustworthy.
 
     ``timed=True`` (a traced replica) queues each frame with the
-    ``perf_counter`` time it was queued and adds its wait until
-    ``writer.write`` to ``wait_s``; otherwise the queue holds bare frames.
+    ``perf_counter`` time it was queued and adds its wait until the
+    flush's ``transport.write`` to ``wait_s``; otherwise the queue holds
+    bare frames.
     """
 
     REORDER_EVERY = 4     # hold every 4th frame ...
     REORDER_SKIP = 12     # ... until 12 later frames have been sent
+    REORDER_QUIET_S = 0.25   # ... or no frame for this long
 
     def __init__(self, src: int, dst: int,
                  addr_fn: Callable[[], Optional[tuple]], *,
@@ -321,20 +417,25 @@ class PeerChannel:
         self.reorder = reorder
         self.timed = timed
         self.on_frame = on_frame       # clients: replies ride this socket
+        self._loop = asyncio.get_running_loop()
         self._q: deque = deque()
         self._held: Optional[bytes] = None     # reorder twin: displaced frame
         self._held_skip = 0                    # frames left to jump over
         self._sent_ctr = 0                     # selects every Nth frame
-        self._wake = asyncio.Event()
+        self._release = None                   # ... and its quiet timer
+        self._transport = None         # the live connection's, once dialed
+        self._paused = False           # its buffer is over the high-water
+        self._flush_due = False        # a flush is scheduled
         self._closed = False
         # soak-visible stats: every one of these is bounded per the
         # module contract; queue_hwm <= max_queue is asserted in tests
         self.sent = 0
+        self.writes = 0                        # transport.write calls
         self.wait_s = 0.0                      # timed: queued -> written
         self.dropped = 0
         self.reconnects = 0
         self.queue_hwm = 0
-        self._task = asyncio.ensure_future(self._run())
+        self._dial = self._loop.create_task(self._connect())
 
     # -- send side (sync, called from protocol handlers) ---------------------
 
@@ -346,113 +447,118 @@ class PeerChannel:
                 self._push(data)
                 self._held_skip -= 1
                 if self._held_skip <= 0:
-                    held, self._held = self._held, None
-                    self._push(held)       # displaced frame lands late
+                    self._release_held()   # displaced frame lands late
+                else:
+                    self._hold_quietly()
                 return
             self._sent_ctr += 1
             if self._sent_ctr % self.REORDER_EVERY == 0:
                 self._held = data
                 self._held_skip = self.REORDER_SKIP
+                self._hold_quietly()
                 return
         self._push(data)
 
+    def _hold_quietly(self) -> None:
+        """(Re)arm the reorder twin's quiet-period release."""
+        if self._release is not None:
+            self._release.cancel()
+        self._release = self._loop.call_later(self.REORDER_QUIET_S,
+                                              self._release_held)
+
+    def _release_held(self) -> None:
+        if self._release is not None:
+            self._release.cancel()
+            self._release = None
+        held, self._held = self._held, None
+        if held is not None and not self._closed:
+            self._push(held)
+
     def _push(self, data: bytes) -> None:
-        if len(self._q) >= self.max_queue:
-            self._q.popleft()              # drop-oldest: retransmit
+        q = self._q
+        if len(q) >= self.max_queue:
+            q.popleft()                    # drop-oldest: retransmit
             self.dropped += 1              # timers / client retries
         if self.timed:                     # re-drive consensus traffic
             data = (time.perf_counter(), data)
-        self._q.append(data)
-        if len(self._q) > self.queue_hwm:
-            self.queue_hwm = len(self._q)
-        self._wake.set()
+        q.append(data)
+        if len(q) > self.queue_hwm:
+            self.queue_hwm = len(q)
+        self._schedule()
 
-    # -- connection loop -----------------------------------------------------
+    def _schedule(self) -> None:
+        if (not self._flush_due and self._q and not self._paused
+                and self._transport is not None):
+            self._flush_due = True
+            self._loop.call_soon(self._flush)
 
-    async def _run(self) -> None:
+    def _flush(self) -> None:
+        """Write every queued frame with one ``transport.write``."""
+        self._flush_due = False
+        q = self._q
+        transport = self._transport
+        if transport is None or self._paused or not q:
+            return
+        if self.timed:
+            now = time.perf_counter()
+            self.wait_s += sum(now - queued for queued, _ in q)
+            data = b"".join([frame for _, frame in q])
+        else:
+            data = b"".join(q)
+        self.sent += len(q)
+        self.writes += 1
+        q.clear()
+        transport.write(data)
+
+    # -- connection ----------------------------------------------------------
+
+    async def _connect(self) -> None:
+        """Dial until connected, re-reading the address on every attempt."""
         backoff = 0.05
-        while not self._closed:
+        while True:
             addr = self.addr_fn()
-            if addr is None:
-                await asyncio.sleep(backoff)
-                backoff = min(backoff * 2, 1.0)
-                continue
-            try:
-                reader, writer = await asyncio.open_connection(*addr)
-            except OSError:
-                self.reconnects += 1
-                await asyncio.sleep(backoff)
-                backoff = min(backoff * 2, 1.0)
-                continue
-            backoff = 0.05
-            writer.write(encode_hello(self.src))
-            reader_task = None
-            if self.on_frame is not None:
-                reader_task = asyncio.ensure_future(
-                    self._read_loop(reader, writer))
-            try:
-                while not self._closed:
-                    if writer.transport.is_closing():
-                        # asyncio swallows writes to a dead transport;
-                        # surface it so the dial loop reconnects (the
-                        # frames already handed over are lost — drop
-                        # semantics, retries re-drive)
-                        raise ConnectionResetError
-                    if not self._q:
-                        self._wake.clear()
-                        try:           # bounded wait: the is_closing
-                            await asyncio.wait_for(   # poll above must
-                                self._wake.wait(), timeout=0.25)  # run
-                        except asyncio.TimeoutError:  # on idle channels
-                            # reorder twin: a frame held for a full idle
-                            # window is released rather than held
-                            # forever (liveness); releasing only after
-                            # a quiet period — not the moment the queue
-                            # drains — is what lets the displacement
-                            # actually straddle later frames on a fast
-                            # loopback link
-                            if self._held is not None and not self._q:
-                                self._push(self._held)
-                                self._held = None
-                        continue
-                    frame = self._q.popleft()
-                    if self.timed:
-                        queued, frame = frame
-                        self.wait_s += time.perf_counter() - queued
-                    writer.write(frame)
-                    self.sent += 1
-                    if not self._q:
-                        await writer.drain()
-            except (ConnectionError, OSError):
-                self.reconnects += 1
-            finally:
-                if reader_task is not None:
-                    reader_task.cancel()
-                writer.close()
+            if addr is not None:
+                try:
+                    await self._loop.create_connection(
+                        lambda: _ChannelLink(self), *addr)
+                    return
+                except OSError:
+                    self.reconnects += 1
+            await asyncio.sleep(backoff)
+            backoff = min(backoff * 2, 1.0)
 
-    async def _read_loop(self, reader, writer) -> None:
-        from repro.transport.codec import read_frame
-        try:
-            while True:
-                body = await read_frame(reader)
-                self.on_frame(body)
-        except (asyncio.IncompleteReadError, ConnectionError, OSError,
-                ValueError):
-            # EOF / reset: kill the transport so the write side's
-            # is_closing poll triggers the reconnect path
-            writer.transport.abort()
+    def _up(self, transport) -> None:
+        if self._closed:
+            transport.close()
+            return
+        transport.write(encode_hello(self.src))
+        self._transport = transport
+        self._schedule()
+
+    def _down(self, transport) -> None:
+        if transport is not self._transport:
+            return
+        self._transport = None
+        self._paused = False
+        if not self._closed:
+            self.reconnects += 1
+            self._dial = self._loop.create_task(self._connect())
 
     async def close(self) -> None:
         self._closed = True
-        self._wake.set()
-        self._task.cancel()
+        if self._release is not None:
+            self._release.cancel()
+        self._dial.cancel()
         try:
-            await self._task
-        except (asyncio.CancelledError, Exception):
+            await self._dial
+        except asyncio.CancelledError:
             pass
+        if self._transport is not None:
+            self._transport.close()
+            self._transport = None
 
     def stats(self) -> dict:
-        return {"dst": self.dst, "sent": self.sent, "wait_s": self.wait_s,
-                "dropped": self.dropped,
+        return {"dst": self.dst, "sent": self.sent, "writes": self.writes,
+                "wait_s": self.wait_s, "dropped": self.dropped,
                 "reconnects": self.reconnects, "queue_hwm": self.queue_hwm,
                 "queue_len": len(self._q), "max_queue": self.max_queue}

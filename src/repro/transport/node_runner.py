@@ -9,10 +9,16 @@ directory (atomically, tmp + rename) and peers re-read the file on every
 dial attempt, so a replica that restarts on a fresh port is found
 without any control plane.
 
-Inbound connections self-identify with a hello frame: ids below ``n``
-are replicas (frames are protocol messages), ids at or above ``n`` are
-clients — their socket is also registered as the reply route
-(:meth:`NetContext.register_client_writer`).
+Inbound connections are ``asyncio.Protocol`` instances
+(:class:`Inbound`): each read's complete frames are cut from the
+connection's buffer and delivered (``decode_body`` → ``NetContext.deliver``)
+in arrival order inside that read's ``data_received``; no task is woken
+per frame. A connection self-identifies with a hello, its first frame:
+ids below ``n`` are replicas (frames are protocol messages), ids at or
+above ``n`` are clients — their transport is also registered as the
+reply route (:meth:`NetContext.register_client_writer`). Outbound, each
+peer's :class:`PeerChannel` writes every frame queued in a loop turn with
+one socket write.
 
 On SIGTERM/SIGINT the runner dumps its raw tracer events to
 ``node-<id>.trace.jsonl`` and channel/engine counters to
@@ -23,8 +29,8 @@ traces through the same ``canonical_events`` path simulator runs use.
 ``applied``, ``recovering``, ``isolated``, ...), the slow path's whole-run
 ``SlowCounters`` (``slow_instances``, ``slow_ops``, ``slow_hold_s``,
 ``slow_backlog``; a protocol without the slow path has none) and each
-outbound channel's (``sent``, ``wait_s``, ``dropped``, ``reconnects``,
-``queue_hwm``, ...).
+outbound channel's (``sent``, ``writes``, ``wait_s``, ``dropped``,
+``reconnects``, ``queue_hwm``, ...).
 With ``--trace`` it also holds ``apply_digest`` (``core/rsm.py``), equal
 on replicas that applied each object's writes in the same order, and the
 replica's host is sampled, every row stamped on the cluster clock
@@ -63,9 +69,9 @@ from pathlib import Path
 from repro.core import weights as W
 from repro.core.rsm import apply_digest
 from repro.scenario.registry import protocol_class
-from repro.transport.codec import (decode_body, decode_hello, read_frame,
+from repro.transport.codec import (decode_body, decode_hello,
                                    slow_instance_fits)
-from repro.transport.net import NetContext, PeerChannel
+from repro.transport.net import FrameProtocol, NetContext, PeerChannel
 
 LAG_PERIOD_S = 0.01         # the loop-lag probe's sleep
 HOST_PERIOD_S = 0.25        # between two ``host`` rows
@@ -99,24 +105,26 @@ def read_addr(run_dir: Path, node_id: int):
         return None
 
 
-async def _serve_connection(ctx: NetContext, reader, writer) -> None:
-    try:
-        peer_id = decode_hello(await read_frame(reader))
-    except (asyncio.IncompleteReadError, ConnectionError, OSError,
-            ValueError, KeyError):
-        writer.close()
-        return
-    if peer_id >= ctx.n:
-        ctx.register_client_writer(peer_id, writer)
-    try:
-        while True:
-            msg = decode_body(await read_frame(reader))
-            ctx.deliver(msg)
-    except (asyncio.IncompleteReadError, ConnectionError, OSError,
-            ValueError):
-        pass
-    finally:
-        writer.close()
+class Inbound(FrameProtocol):
+    """One inbound connection: its hello, then protocol frames, each
+    delivered as it is cut from the read buffer. A hello that does not
+    decode, or a frame over ``MAX_FRAME``, aborts the connection."""
+
+    def __init__(self, ctx: NetContext):
+        super().__init__(self._hello)
+        self.ctx = ctx
+
+    def _hello(self, body: bytes) -> None:
+        try:
+            peer_id = decode_hello(body)
+        except KeyError as e:
+            raise ValueError(f"not a hello frame: {e}") from None
+        if peer_id >= self.ctx.n:
+            self.ctx.register_client_writer(peer_id, self.transport)
+        self.on_body = self._deliver
+
+    def _deliver(self, body: bytes) -> None:
+        self.ctx.deliver(decode_body(body))
 
 
 def served_replica(protocol: str, node_id: int, ctx: NetContext,
@@ -148,8 +156,8 @@ async def serve(args) -> None:
     replica.HB_TIMEOUT = replica.HB_TIMEOUT * args.hb_scale
     ctx.add_node(replica)
 
-    server = await asyncio.start_server(
-        lambda r, w: _serve_connection(ctx, r, w), "127.0.0.1", 0)
+    server = await asyncio.get_running_loop().create_server(
+        lambda: Inbound(ctx), "127.0.0.1", 0)
     port = server.sockets[0].getsockname()[1]
     write_port_file(run_dir, args.node_id, port)
 

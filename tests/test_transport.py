@@ -248,6 +248,294 @@ def test_facade_broadcast_is_its_single_posts(kind):
 
 
 # ---------------------------------------------------------------------------
+# the bulk path: one flush per link per loop turn, one parse per read
+# ---------------------------------------------------------------------------
+
+async def _until(cond, timeout=10.0):
+    import asyncio
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, "timed out"
+        await asyncio.sleep(0.005)
+
+
+class _Peer:
+    """A stub replica's listening socket: records the bytes each inbound
+    connection brings, and can stop reading them."""
+
+    def __init__(self, read=True):
+        self.read = read
+        self.conns = []
+
+    async def start(self):
+        import asyncio
+
+        peer = self
+
+        class Conn(asyncio.Protocol):
+            def connection_made(self, transport):
+                self.transport, self.data = transport, bytearray()
+                peer.conns.append(self)
+                if not peer.read:
+                    transport.pause_reading()
+
+            def data_received(self, data):
+                self.data += data
+
+        self.server = await asyncio.get_running_loop().create_server(
+            Conn, "127.0.0.1", 0)
+        self.addr = self.server.sockets[0].getsockname()[:2]
+        return self
+
+    def received(self):
+        return b"".join(bytes(c.data) for c in self.conns)
+
+    def kill(self):
+        self.server.close()
+        for c in self.conns:
+            c.transport.abort()
+
+
+def _frames(n, size=0):
+    return [encode_msg(Msg("fast_propose", 0, 1,
+                           {"ops": [_op(i, size)], "fb": i, "pad": "x" * size},
+                           1)) for i in range(n)]
+
+
+@pytest.mark.parametrize("timed", [False, True], ids=["untimed", "timed"])
+def test_frames_of_one_turn_leave_in_one_write(timed):
+    """Frames sent in one loop turn reach the peer as one write of their
+    concatenation, in order; an idle link flushes a lone frame at once."""
+    import asyncio
+
+    from repro.transport.net import PeerChannel
+
+    async def go():
+        peer = await _Peer().start()
+        chan = PeerChannel(7, 1, lambda: peer.addr, timed=timed)
+        await _until(lambda: chan._transport is not None)
+        hello = encode_hello(7)
+        frames = _frames(3)
+        for f in frames:
+            chan.send(f)
+        await _until(lambda: len(peer.received()) >= len(hello) + sum(
+            map(len, frames)))
+        assert peer.received() == hello + b"".join(frames)
+        assert (chan.sent, chan.writes, chan.dropped) == (3, 1, 0)
+        assert (chan.wait_s > 0) == timed
+        chan.send(frames[0])               # alone in its turn
+        await _until(lambda: chan.sent == 4)
+        assert chan.writes == 2
+        stats = chan.stats()
+        await chan.close()
+        peer.kill()
+        return stats
+
+    stats = asyncio.run(go())
+    assert stats["writes"] == 2 and stats["sent"] == 4
+    assert stats["queue_len"] == 0 and stats["reconnects"] == 0
+
+
+def _inbound_stream():
+    """A replica's hello and then frames of several sizes, and the
+    messages they carry."""
+    msgs = [Msg("hb", 2, 0, {"t": 0.25}, 0),
+            Msg("fast_propose", 2, 0,
+                {"ops": [_op(i) for i in range(10)], "fb": 5}, 10),
+            Msg("slow_commit", 2, 0, {"ops": [_op(1)], "deps": {1: [2]}}, 1),
+            Msg("fast_accept", 2, 0, {"fb": 5, "ok": True}, 0)]
+    frames = [encode_msg(m) for m in msgs]
+    return encode_hello(2) + b"".join(frames), frames
+
+
+class _Transport:
+    """What an inbound protocol and the reply route use of a transport."""
+
+    def __init__(self, buffered=0):
+        self.writes = []
+        self.buffered = buffered
+        self.aborted = self.closing = False
+
+    def write(self, data):
+        self.writes.append(data)
+
+    def abort(self):
+        self.aborted = True
+
+    def is_closing(self):
+        return self.closing or self.aborted
+
+    def get_write_buffer_size(self):
+        return self.buffered
+
+
+def _inbound(n=5):
+    from repro.transport.net import NetContext
+    from repro.transport.node_runner import Inbound
+
+    ctx = NetContext(0, n, epoch=time.time())
+    sink = _Sink(0)
+    sink.on_message = lambda msg, now: sink.got.append(encode_msg(msg))
+    ctx.add_node(sink)
+    proto = Inbound(ctx)
+    transport = _Transport()
+    proto.connection_made(transport)
+    return proto, transport, sink, ctx
+
+
+@pytest.mark.parametrize("pieces", ["two", "bytewise"])
+def test_inbound_frames_survive_every_cut(pieces, body_format):
+    """Cut anywhere, mid-header and mid-body included, the inbound
+    connection delivers the same messages in the same order (each
+    delivered message encodes to the frame it came in)."""
+    stream, want = _inbound_stream()
+    if pieces == "two":
+        cuts = [[stream[:k], stream[k:]] for k in range(len(stream) + 1)]
+    else:
+        cuts = [[stream[i:i + 1] for i in range(len(stream))]]
+    for chunks in cuts:
+        proto, transport, sink, _ = _inbound()
+        for chunk in chunks:
+            proto.data_received(chunk)
+        assert sink.got == want
+        assert not transport.aborted and not proto._buf
+
+
+@pytest.mark.parametrize("bad", ["oversize", "not_a_hello", "oversize_hello"])
+def test_inbound_rejects_a_corrupt_stream(bad):
+    """A length over ``MAX_FRAME`` closes the connection as soon as its
+    header is in, and so does a first frame that is not a hello; nothing
+    after it is delivered."""
+    from repro.transport.codec import HEADER, MAX_FRAME
+    stream, _ = _inbound_stream()
+    over = HEADER.pack(MAX_FRAME + 1)
+    data = {"oversize": encode_hello(2) + over + stream[len(encode_hello(2)):],
+            "not_a_hello": encode_msg(Msg("hb", 2, 0, {"t": 0.25}, 0)),
+            "oversize_hello": over}[bad]
+    proto, transport, sink, _ = _inbound()
+    proto.data_received(data)
+    assert transport.aborted and sink.got == []
+
+
+@pytest.mark.parametrize("case", ["batched", "over_limit", "closing"])
+def test_client_replies_of_one_turn_leave_in_one_write(case):
+    """A client's replies posted in one loop turn leave in one write of
+    their concatenation; a client whose socket buffer is over
+    ``WRITE_BUF_LIMIT`` loses replies, and a closed one loses its route."""
+    import asyncio
+
+    from repro.transport.net import WRITE_BUF_LIMIT
+
+    async def go():
+        proto, transport, _, ctx = _inbound()
+        proto.data_received(encode_hello(9)[:5])
+        proto.data_received(encode_hello(9)[5:])    # client 9 dialed in
+        transport.buffered = {"over_limit": WRITE_BUF_LIMIT + 1}.get(case, 0)
+        transport.closing = case == "closing"
+        frames = [encode_msg(Msg("client_reply", 0, 9, {"op_ids": [i]}, 0))
+                  for i in range(3)]
+        for i in range(3):
+            ctx.post(Msg("client_reply", 0, 9, {"op_ids": [i]}, 0))
+        assert transport.writes == []        # nothing before the turn ends
+        await asyncio.sleep(0)
+        return transport.writes, frames, ctx
+
+    writes, frames, ctx = asyncio.run(go())
+    if case == "batched":
+        assert writes == [b"".join(frames)] and ctx.dropped_no_route == 0
+    else:
+        assert writes == [] and ctx.dropped_no_route == 3
+    assert (9 in ctx._senders) == (case != "closing")
+
+
+def test_channel_to_a_stalled_peer_stays_bounded():
+    """A peer that stops reading: the transport pauses writing, frames
+    wait in the bounded queue (drop-oldest, ``queue_hwm <= max_queue``,
+    ``dropped`` counting the overflow), and when the peer reads again the
+    queue drains through the resumed flush."""
+    import asyncio
+
+    from repro.transport.net import PeerChannel
+
+    async def go():
+        peer = await _Peer(read=False).start()
+        chan = PeerChannel(0, 1, lambda: peer.addr, max_queue=8)
+        await _until(lambda: peer.conns)
+        frames = _frames(4, size=64 * 1024)
+        n = 0
+        while not (chan._paused and chan.dropped):
+            for f in frames:
+                chan.send(f)
+                n += 1
+            await asyncio.sleep(0)
+            assert n < 4000, "the transport never paused"
+        assert chan.queue_hwm <= chan.max_queue
+        assert chan.sent + chan.dropped + len(chan._q) == n
+        peer.conns[0].transport.resume_reading()
+        await _until(lambda: not chan._q)
+        hello = encode_hello(0)
+        await _until(lambda: len(peer.received()) >= len(hello)
+                     + chan.sent * len(frames[0]))
+        assert chan.sent + chan.dropped == n and chan.writes < chan.sent
+        await chan.close()
+        peer.kill()
+
+    asyncio.run(go())
+
+
+def test_channel_redials_a_restarted_peer_on_its_new_port():
+    """When the peer dies and comes back on a fresh port, the channel
+    notices the lost connection, re-resolves ``addr_fn`` and delivers
+    what is sent next over the new connection, hello first."""
+    import asyncio
+
+    from repro.transport.net import PeerChannel
+
+    async def go():
+        old = await _Peer().start()
+        where = [old.addr]
+        chan = PeerChannel(3, 1, lambda: where[0])
+        a, b = _frames(2)
+        chan.send(a)
+        await _until(lambda: old.received() == encode_hello(3) + a)
+        new = await _Peer().start()
+        assert new.addr != old.addr
+        where[0] = new.addr
+        old.kill()
+        await _until(lambda: chan.reconnects >= 1)
+        chan.send(b)
+        await _until(lambda: new.received() == encode_hello(3) + b)
+        assert (chan.sent, chan.dropped) == (2, 0)
+        await chan.close()
+        new.kill()
+
+    asyncio.run(go())
+
+
+def test_reorder_twin_displaces_and_releases_when_quiet():
+    """The mutation twin holds every ``REORDER_EVERY``-th frame until
+    ``REORDER_SKIP`` later frames are queued, and releases a held frame
+    that no later frame displaces after ``REORDER_QUIET_S``."""
+    import asyncio
+
+    from repro.transport.net import PeerChannel
+
+    async def go():
+        chan = PeerChannel(0, 1, lambda: None, reorder=True)
+        for i in range(1, 21):
+            chan.send(i)
+        order = list(chan._q)
+        await asyncio.sleep(chan.REORDER_QUIET_S * 2)
+        late = list(chan._q)[len(order):]
+        await chan.close()
+        return order, late
+
+    order, late = asyncio.run(go())
+    assert order == [1, 2, 3, *range(5, 17), 4, 17, 18, 19]
+    assert late == [20]
+
+
+# ---------------------------------------------------------------------------
 # loopback cluster: real histories through the real checker
 # ---------------------------------------------------------------------------
 
